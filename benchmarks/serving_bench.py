@@ -128,6 +128,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.quant.quantize import QuantizedTensor
 from repro.serving import (Request, SamplingConfig, ServingEngine,
@@ -1398,6 +1399,7 @@ def main() -> None:
                     choices=list(_SWEEPS) + ["all"],
                     help="which sweep to run (default: all)")
     args = ap.parse_args()
+    enable_compile_cache()
     sweeps = _SWEEPS if args.sweep == "all" else (args.sweep,)
     print("name,us_per_call,derived")
     for name, us, derived in run(sweeps):

@@ -13,16 +13,17 @@ unpack cost is VREG shifts hidden under the cache stream.
 Same grid and scratch layout as ``decode_attention.py``:
 (B, Hkv, S/bk), online-softmax (m, l, acc) state in VMEM scratch,
 grouped queries (G, D) per KV head. The q4_0 nibble-unpack
-(mask/shift/sign-extend) is fused into the K/V block load; dequantized
+(shift/sign-extend) is fused into the K/V block load; dequantized
 values are rounded to bf16 before the dot so the kernel feeds the MXU
 the exact values the XLA path (``dequantize_rows`` -> bf16 view) sees.
 
 Payload layouts (see quant/quantize.py row-wise helpers):
   q8_0: k/v (B, Hkv, S, D) int8;      scales (B, Hkv, S, D//g) bf16
-  q4_0: k/v (B, Hkv, S, D//2) int8 (two nibbles per byte, low = even
-        feature index); scales as above. g = kv_group_size(D, group,
-        fmt) — inferred here from the scales' last dim, so
-        non-group-aligned head dims (any divisor group) just work.
+  q4_0: k/v (B, Hkv, S, D//2) int8 (two nibbles per byte: byte j
+        holds feature j low and feature j + D/2 high); scales as
+        above. g = kv_group_size(D, group, fmt) — inferred here from
+        the scales' last dim, so non-group-aligned head dims (any
+        divisor group) just work.
 """
 from __future__ import annotations
 
@@ -33,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.quant.quantize import int4_nibbles
 
 DEFAULT_BK = 1024
 NEG_INF = -1e30
@@ -47,20 +50,16 @@ def _dequant_rows(qblk: jax.Array, sblk: jax.Array, fmt: str) -> jax.Array:
     accumulation, the same op the XLA oracle runs.
     """
     if fmt == "q4_0":
-        lo = (qblk & 0x0F).astype(jnp.int8)
-        hi = ((qblk >> 4) & 0x0F).astype(jnp.int8)
-        lo = jnp.where(lo > 7, lo - 16, lo)
-        hi = jnp.where(hi > 7, hi - 16, hi)
-        # interleave back to feature order: even idx = low nibble
-        q = jnp.stack([lo, hi], axis=-1).reshape(
-            qblk.shape[0], 2 * qblk.shape[1])
+        # half-split rows: low nibbles are features [0, D/2), high
+        # nibbles [D/2, D) — a lane concat, never a lane interleave
+        q = jnp.concatenate(int4_nibbles(qblk), axis=-1)
     else:
         q = qblk
-    bk, d = q.shape
-    g = d // sblk.shape[-1]
-    qg = q.astype(jnp.float32).reshape(bk, d // g, g)
-    x = qg * sblk.astype(jnp.float32)[..., None]
-    return x.reshape(bk, d).astype(jnp.bfloat16)
+    # expand the (bk, D//g) scales along lanes instead of splitting the
+    # feature lanes into (D//g, g) groups, a shape cast Mosaic refuses
+    g = q.shape[-1] // sblk.shape[-1]
+    s = jnp.repeat(sblk.astype(jnp.float32), g, axis=-1)
+    return (q.astype(jnp.float32) * s).astype(jnp.bfloat16)
 
 
 def _decode_quant_kernel(lens_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,

@@ -1,10 +1,9 @@
 """Public jit'd kernel wrappers — the dispatch point between Pallas and XLA.
 
 Models call these; the paper's hardware-aware planner (core/dispatch)
-decides per-GEMM whether the Pallas path runs. On this CPU container
-Pallas executes in interpret mode (``REPRO_PALLAS_INTERPRET=1`` default
-when no TPU is present); on a real pod the same call sites compile to
-Mosaic.
+decides per-GEMM whether the Pallas path runs. Off a TPU, Pallas
+executes in interpret mode; on a TPU the same call sites always compile
+to Mosaic (see ``_interpret_default``).
 """
 from __future__ import annotations
 
@@ -29,10 +28,14 @@ SUBLANE = 8
 
 
 def _interpret_default() -> bool:
+    """Interpret mode off a TPU only: on a TPU backend the kernels always
+    compile to Mosaic, whatever ``REPRO_PALLAS_INTERPRET`` says."""
+    if jax.default_backend() == "tpu":
+        return False
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is not None:
         return env.strip().lower() not in ("0", "false", "no", "off", "")
-    return jax.default_backend() != "tpu"
+    return True
 
 
 def matmul(x: jax.Array, w: Union[jax.Array, QuantizedTensor], *,

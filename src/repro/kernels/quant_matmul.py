@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.quant.quantize import QuantizedTensor
+from repro.quant.quantize import QuantizedTensor, int4_nibbles
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -42,10 +42,7 @@ def _dequant_block_q8(qblk, sblk, group):
 
 def _dequant_block_q4(qblk, sblk, group):
     # qblk packed: (bk//2, bn) int8, two nibbles per byte
-    lo = (qblk & 0x0F).astype(jnp.int8)
-    hi = ((qblk >> 4) & 0x0F).astype(jnp.int8)
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
+    lo, hi = int4_nibbles(qblk)
     k2, bn = qblk.shape
     q = jnp.stack([lo, hi], axis=1).reshape(2 * k2, bn)  # interleaved
     q = q.astype(jnp.float32).reshape(2 * k2 // group, group, bn)

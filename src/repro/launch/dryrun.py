@@ -6,7 +6,10 @@ import os
 # gets loop-hoisted over scan-over-layers so the whole stacked KV cache
 # materializes in f32. TPU executes bf16 dots natively, so disabling
 # the pass (we only compile, never run) gives TPU-realistic
-# memory/bytes numbers. See EXPERIMENTS.md §Dry-run.
+# memory/bytes numbers. See EXPERIMENTS.md §Dry-run. The dry-run only
+# compiles, on host devices, so it pins the CPU platform: on a machine
+# with a TPU it must never take the chip from the process that holds it.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512"
                            " --xla_disable_hlo_passes=float-normalization-bf16")
@@ -163,6 +166,7 @@ def _compile_probe_subprocess(cfg: ModelConfig, shape: InputShape,
                  for f in dataclasses.fields(cfg)}
     code = (
         "import os\n"
+        "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
         "os.environ['XLA_FLAGS'] = ('--xla_force_host_platform_device_count=512"
         " --xla_disable_hlo_passes=float-normalization-bf16')\n"
         "import json, dataclasses\n"

@@ -7,24 +7,26 @@ jax import; smoke tests and benches see the real single device.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # AxisType landed after jax 0.4.x; Auto is the old default anyway
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:
-    _AXIS_KW = lambda n: {}
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes. Without ``axis_types`` current
+    JAX makes the axes Explicit, and the sharding rules' implicit
+    resharding (e.g. the embedding gather) then raises."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
     """1x1 mesh on the real local device (smoke tests, examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_AXIS_KW(2))
+    return make_mesh((1, 1), ("data", "model"))

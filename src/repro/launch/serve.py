@@ -43,6 +43,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.quant import quantize_tree
 from repro.serving import (QueueFull, Request, SamplingConfig,
@@ -395,9 +396,9 @@ def _run_frontend(engine: ServingEngine, cfg, args) -> int:
     return asyncio.run(drive())
 
 
-def main(argv: Optional[List[str]] = None) -> None:
-    args = build_parser().parse_args(argv)
-
+def build_engine(args) -> ServingEngine:
+    """The engine ``main`` serves, from parsed CLI arguments: random
+    weights from a fixed seed, quantized per ``--precision``."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -422,6 +423,14 @@ def main(argv: Optional[List[str]] = None) -> None:
                            prefix_cache=args.prefix_cache,
                            max_queue=args.max_queue)
     engine.audit_every_step = args.audit
+    return engine
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    engine = build_engine(args)
+    cfg = engine.cfg
 
     # Warmup pays jit compile; reset() keeps the compiled executables
     # but zeroes the stats so the timed run is compile-excluded (the

@@ -620,7 +620,7 @@ class Model:
     # -- single-request reference loop (serving oracle) --------------------------
     def reference_decode(self, params, prompt, max_new_tokens: int,
                          eos_id: int = -1, *, max_len: int = 64,
-                         stepwise_prefill: bool = True):
+                         stepwise_prefill: bool = True, batch: int = 1):
         """Greedy single-request decode loop — the oracle the serving
         property suite holds the continuous-batching engine to.
 
@@ -630,6 +630,12 @@ class Model:
         prompt length); ``False`` uses the fused ``prefill`` (the
         stall-admission path). Returns the generated token list
         (first sampled token included, stops at EOS / max_new).
+
+        ``batch`` runs the request in every row of a ``batch``-row step
+        and reads row 0. Rows never mix, but a bf16 matmul can round
+        differently at a different batch width, and a near-tie in the
+        logits then flips a greedy token; an oracle compiled at the
+        engine's slot count rules that out.
         """
         if max_new_tokens <= 0:
             return []                # zero budget: nothing to generate
@@ -637,17 +643,20 @@ class Model:
             self._ref_jits = (jax.jit(self.prefill),
                               jax.jit(self.decode_step))
         pre, dec = self._ref_jits
-        cache = self.init_cache(1, max_len)
+        cache = self.init_cache(batch, max_len)
         prompt = jnp.asarray(prompt, jnp.int32)
         if stepwise_prefill:
             for t in prompt:
-                logits, cache = dec(params, t[None, None], cache)
+                logits, cache = dec(params, jnp.full((batch, 1), t),
+                                    cache)
         else:
-            logits, cache = pre(params, {"tokens": prompt[None]}, cache)
+            logits, cache = pre(
+                params, {"tokens": jnp.tile(prompt[None], (batch, 1))},
+                cache)
         out = [int(jnp.argmax(logits[0]))]
         while len(out) < max_new_tokens and out[-1] != eos_id:
             logits, cache = dec(
-                params, jnp.asarray([[out[-1]]], jnp.int32), cache)
+                params, jnp.full((batch, 1), out[-1], jnp.int32), cache)
             out.append(int(jnp.argmax(logits[0])))
         return out
 

@@ -8,6 +8,7 @@ they come from the same source of truth.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -33,6 +34,19 @@ def is_spec_tree_leaf(x) -> bool:
     return isinstance(x, ParamSpec)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 3))
+def _scaled_normal(key, shape, std, dtype):
+    """One leaf's ``normal * std`` cast, fused by XLA: on a TPU no f32
+    copy of the leaf is materialized (a stacked gate/up leaf of a 7B
+    model would take 10.8 GB in f32). The no-op ``reduce_precision``
+    keeps XLA from folding ``std`` into the sampler's own ``* sqrt(2)``,
+    which would round differently from the eager ops and change bits."""
+    z = jax.lax.reduce_precision(
+        jax.random.normal(key, shape, jnp.float32),
+        exponent_bits=8, mantissa_bits=23)
+    return (z * std).astype(dtype)
+
+
 def init_params(specs, rng: jax.Array, dtype=jnp.bfloat16):
     leaves, treedef = jax.tree_util.tree_flatten(
         specs, is_leaf=is_spec_tree_leaf)
@@ -51,12 +65,10 @@ def init_params(specs, rng: jax.Array, dtype=jnp.bfloat16):
             # embeddings: std 1/sqrt(d_model) so tied unembedding gives
             # O(1) logits
             std = spec.scale * (spec.shape[-1] ** -0.5)
-            return (jax.random.normal(key, spec.shape, jnp.float32) * std
-                    ).astype(dtype)
+            return _scaled_normal(key, spec.shape, jnp.float32(std), dtype)
         fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
         std = spec.scale * (fan_in ** -0.5)
-        return (jax.random.normal(key, spec.shape, jnp.float32) * std
-                ).astype(dtype)
+        return _scaled_normal(key, spec.shape, jnp.float32(std), dtype)
 
     return jax.tree_util.tree_unflatten(
         treedef, [mk(s, k) for s, k in zip(leaves, keys)])

@@ -103,18 +103,24 @@ def pack_int4(q: jax.Array) -> jax.Array:
     return (lo | (hi << 4)).astype(jnp.int8)
 
 
+def int4_nibbles(packed: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Sign-extended (low, high) nibbles of packed int8 bytes, as int32.
+
+    The shifts run on 32-bit lanes: Mosaic cannot legalize an int8
+    arithmetic right shift, and the Pallas kernels share this helper.
+    """
+    q = packed.astype(jnp.int32)
+    return (q << 28) >> 28, (q << 24) >> 28
+
+
 def unpack_int4(packed: jax.Array) -> jax.Array:
     """Inverse of :func:`pack_int4` → int8 values in [-8, 7]."""
-    lo = (packed & 0x0F).astype(jnp.int8)
-    hi = ((packed >> 4) & 0x0F).astype(jnp.int8)
-    # sign-extend 4-bit two's complement
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
+    lo, hi = int4_nibbles(packed)
     k2 = packed.shape[-2]
     out_shape = packed.shape[:-2] + (2 * k2,) + packed.shape[-1:]
     # interleave: stack -> (..., k2, 2, n), row-major reshape -> (..., 2*k2, n)
     out = jnp.stack([lo, hi], axis=-2)
-    return out.reshape(out_shape)
+    return out.reshape(out_shape).astype(jnp.int8)
 
 
 def _group_scales(w: jax.Array, group: int, qmax: float):
@@ -194,21 +200,23 @@ def kv_group_size(dim: int, group: int, fmt: str) -> int:
 
 
 def pack_int4_rows(q: jax.Array) -> jax.Array:
-    """Pack int4 values in [-8, 7] pairwise along the LAST axis."""
+    """Pack int4 values in [-8, 7] along the LAST axis, half-split.
+
+    Byte j of a d-wide row holds feature j in its low nibble and
+    feature j + d/2 in its high one, so unpacking is a lane
+    concatenation of the two halves. (An even/odd pair layout needs a
+    lane interleave, which Mosaic refuses at head dim 128.)
+    """
     assert q.shape[-1] % 2 == 0, q.shape
-    lo = q[..., 0::2] & 0x0F
-    hi = q[..., 1::2] & 0x0F
+    h = q.shape[-1] // 2
+    lo = q[..., :h] & 0x0F
+    hi = q[..., h:] & 0x0F
     return (lo | (hi << 4)).astype(jnp.int8)
 
 
 def unpack_int4_rows(packed: jax.Array) -> jax.Array:
     """Inverse of :func:`pack_int4_rows` → int8 values in [-8, 7]."""
-    lo = (packed & 0x0F).astype(jnp.int8)
-    hi = ((packed >> 4) & 0x0F).astype(jnp.int8)
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(packed.shape[:-1] + (2 * packed.shape[-1],))
+    return jnp.concatenate(int4_nibbles(packed), axis=-1).astype(jnp.int8)
 
 
 def quantize_rows(x: jax.Array, fmt: str, group: int = 32

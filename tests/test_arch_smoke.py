@@ -192,3 +192,44 @@ def test_param_counts_plausible():
     kimi = get_config("kimi-k2-1t-a32b")
     active = kimi.param_count(active_only=True)
     assert 25e9 <= active <= 40e9, active / 1e9
+
+
+def _eager_init_params(specs, rng, dtype):
+    """The eager per-leaf construction (f32 normal, scale, cast) that
+    ``init_params`` replaced with one fused jit per leaf."""
+    from repro.models.params import is_spec_tree_leaf
+    leaves, treedef = jax.tree_util.tree_flatten(
+        specs, is_leaf=is_spec_tree_leaf)
+    keys = jax.random.split(rng, len(leaves))
+
+    def mk(spec, key):
+        if spec.init == "zeros":
+            return jnp.zeros(spec.shape, dtype)
+        if spec.init == "ones":
+            return jnp.ones(spec.shape, dtype)
+        if spec.init == "small_a":
+            return jnp.full(spec.shape, 4.0, dtype)
+        dim = (spec.shape[-1] if spec.init == "fan_out" or
+               len(spec.shape) < 2 else spec.shape[-2])
+        std = spec.scale * (dim ** -0.5)
+        return (jax.random.normal(key, spec.shape, jnp.float32) * std
+                ).astype(dtype)
+
+    return jax.tree_util.tree_unflatten(
+        treedef, [mk(s, k) for s, k in zip(leaves, keys)])
+
+
+@pytest.mark.parametrize("arch", ["deepseek-7b", "recurrentgemma-2b"])
+def test_init_params_bit_identical_to_eager(arch):
+    """Fusing each leaf's normal-scale-cast under jit changes no bit of
+    the initialized weights."""
+    from repro.models.params import init_params
+    m = Model(reduced(get_config(arch)))
+    specs = m.param_specs()
+    got = init_params(specs, jax.random.PRNGKey(3), jnp.bfloat16)
+    want = _eager_init_params(specs, jax.random.PRNGKey(3), jnp.bfloat16)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))

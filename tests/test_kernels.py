@@ -466,3 +466,16 @@ def test_interpret_default_unset_follows_backend(monkeypatch):
     from repro.kernels import ops
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     assert ops._interpret_default() is (jax.default_backend() != "tpu")
+
+
+@pytest.mark.parametrize("val", [None, "1", "true", "0"])
+def test_tpu_backend_never_interprets(val, monkeypatch):
+    """On a TPU backend the kernels compile to Mosaic whatever
+    REPRO_PALLAS_INTERPRET says: the variable cannot hide the device."""
+    from repro.kernels import ops
+    if val is None:
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", val)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._interpret_default() is False

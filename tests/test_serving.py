@@ -628,3 +628,25 @@ def test_cache_nbytes_matches_live_pytree(kv, page):
         dense = ServingEngine(m, params, slots=2, max_len=64,
                               kv_quant=kv)
         assert small.cache_nbytes() < dense.cache_nbytes()
+
+
+@pytest.mark.parametrize("slots", [2, 4])
+def test_reference_decode_at_engine_width_matches_engine(slots):
+    """Greedy tokens from an engine equal the oracle compiled at its slot
+    count, for every request of a mixed-length stream. (The 1-row oracle
+    may round a near-tied logit differently: bf16 matmuls are not
+    batch-width invariant.)"""
+    cfg = reduced(get_config("deepseek-7b"))
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    reqs = [Request(uid=i, prompt=rng.integers(1, cfg.vocab_size, size=int(
+                rng.integers(4, 13))).astype(np.int32), max_new_tokens=8)
+            for i in range(8)]
+    eng = ServingEngine(m, params, slots=slots, max_len=64)
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    for r in reqs:
+        assert r.output == m.reference_decode(params, r.prompt, 8,
+                                              batch=slots), r.uid

@@ -39,9 +39,6 @@ def test_v3_conflicts_by_design():
 
 
 def test_sanitize_spec_drops_nondividing():
-    import jax
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-
     class FakeMesh:
         axis_names = ("data", "model")
 
@@ -62,7 +59,8 @@ _MESH_SCRIPT = textwrap.dedent("""
     from repro.models import Model
     from repro.models.params import param_pspecs
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2), ("data", "model"))
     rules = rules_for("v2")
     cfg = reduced(get_config("{arch}"), num_kv_heads=2)
     model = Model(cfg)

@@ -86,3 +86,31 @@ def test_dispatch_plan_configures_model():
     logits, _ = m.forward(params, {"tokens": jnp.zeros((2, 8), jnp.int32)})
     assert np.isfinite(np.asarray(logits, np.float32)).all()
     assert p.summary()  # human-readable report exists
+
+
+def test_compile_cache_defers_to_env(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, the entry points' helper
+    leaves the cache to JAX and sets nothing in code."""
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert calls == []
+
+
+def test_compile_cache_fixed_dir_in_checkout(monkeypatch):
+    """Without the variable the cache goes to one fixed directory at the
+    root of the checkout (no temp name, pid or time in the path)."""
+    import pathlib
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    want = str(root / ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert compile_cache.enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)] * 2
