@@ -19,11 +19,9 @@ def main() -> None:
 
     from benchmarks import (decode_microbench, fig4_throughput,
                             fig5_op_breakdown, fig6_matmul_breakdown,
-                            fig8_scheduler_versions, roofline_table,
-                            serving_bench)
+                            fig8_scheduler_versions, roofline_table)
     modules = [fig4_throughput, fig5_op_breakdown, fig6_matmul_breakdown,
-               fig8_scheduler_versions, decode_microbench, serving_bench,
-               roofline_table]
+               fig8_scheduler_versions, decode_microbench, roofline_table]
     if args.only:
         keys = args.only.split(",")
         modules = [m for m in modules

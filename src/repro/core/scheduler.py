@@ -181,7 +181,6 @@ def simulate_megastep(cfg: ModelConfig,
 
     Per-token device time comes from the v2 (fused wave) schedule; each
     megastep then pays ``hw.dispatch_overhead_s`` once per K tokens —
-    the analytic twin of ``benchmarks/serving_bench.py``'s sweep, and
     the napkin math ``core.dispatch.plan`` uses to choose K.
 
     ``donate_carries=False`` charges the un-donated carry boundary
@@ -230,8 +229,7 @@ def simulate_precision(cfg: ModelConfig,
                        donate_carries: bool = True,
                        kernel_backend: str = "pallas",
                        ) -> Dict[str, Dict[int, VersionResult]]:
-    """Serving throughput across weight precisions × megastep K — the
-    analytic twin of ``benchmarks/serving_bench.py``'s precision sweep
+    """Serving throughput across weight precisions × megastep K
     (paper §5.3, Fig 4: F16 vs Q8_0 vs Q4_0).
 
     Each format rebuilds the decode graph with its
@@ -267,8 +265,7 @@ def simulate_kv_precision(cfg: ModelConfig,
                           ) -> Dict[str, Dict[int, Dict[int,
                                                         VersionResult]]]:
     """Serving throughput across KV-cache precisions × megastep K ×
-    context length — the analytic twin of
-    ``benchmarks/serving_bench.py --sweep kv``.
+    context length.
 
     The cache stream is the one that *grows* with context and batch
     (weights don't), so unlike the weight sweep the win here is a
@@ -410,8 +407,7 @@ def simulate_paging(cfg: ModelConfig,
                     donate_carries: bool = True,
                     kernel_backend: str = "pallas",
                     ) -> Dict[int, Dict]:
-    """Dense vs paged KV cache, analytically — the twin of
-    ``serving_bench --sweep paging`` and the model behind
+    """Dense vs paged KV cache, analytically — the model behind
     ``dispatch.plan``'s page-size knob.
 
     Three effects move per page size ``P``:
@@ -531,7 +527,7 @@ def simulate_async_overlap(cfg: ModelConfig,
     win saturates at ``host / (device + host)`` of the serial wall —
     on a device-bound loop the drain hides completely; on a
     host-bound loop the device starves instead and depth stops
-    helping. The analytic twin of ``serving_bench --sweep async``.
+    helping.
     """
     hw = hw or cm.a17_cpu(threads)
     g = build_decoder_graph(cfg, seq=1, kv_len=kv_len, batch=batch,
@@ -572,8 +568,8 @@ def simulate_overload(cfg: ModelConfig,
                       kernel_backend: str = "pallas",
                       ) -> Dict[str, Dict]:
     """Overload behavior of a bounded vs unbounded admission queue,
-    analytically — the twin of ``serving_bench --sweep overload`` and
-    the model behind ``dispatch.plan``'s queue-bound knob.
+    analytically — the model behind ``dispatch.plan``'s queue-bound
+    knob.
 
     Capacity first: a request occupies a slot for
     ``prompt_len + max_new`` chunked substeps (prompt rides in-scan),

@@ -119,5 +119,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(kv_len, qg, k, v)
     return out.reshape(B, Hq, D)

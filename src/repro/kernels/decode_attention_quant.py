@@ -166,5 +166,6 @@ def decode_attention_quant(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention_quant",
     )(kv_len, qg, k_q, k_scale, v_q, v_scale)
     return out.reshape(B, Hq, D)

@@ -123,4 +123,5 @@ def quant_matmul(x: jax.Array, w: QuantizedTensor, *,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="quant_matmul",
     )(x, w.data, w.scales)

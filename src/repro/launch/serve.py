@@ -234,11 +234,15 @@ class AsyncServingFrontend:
                 h.expired = True
                 self.engine.cancel(h.req)
 
-    def _publish(self) -> None:
+    def _publish(self) -> int:
+        """Stream fresh tokens to their callbacks and resolve finished
+        requests; returns the number of tokens streamed."""
         still = []
+        streamed = 0
         for h in self._live:
             fresh = h.req.output[h.sent:]
             h.sent += len(fresh)
+            streamed += len(fresh)
             if h.on_token is not None:
                 for tok in fresh:
                     h.on_token(tok)
@@ -256,13 +260,21 @@ class AsyncServingFrontend:
                     h.future.set_result(list(h.req.output))
             self._sem.release()
         self._live = still
+        return streamed
 
     async def _serve(self) -> None:
+        # each pass between engine steps, and each step with its
+        # hand-off to and from the executor thread, is a host span (see
+        # the Tracing section of ``repro.serving.engine``)
+        span = jax.profiler.TraceAnnotation
         loop = asyncio.get_running_loop()
         while True:
-            self._admit_incoming()
-            self._sweep_cancellations()
-            self._publish()
+            with span("frontend.admit"):
+                self._admit_incoming()
+            with span("frontend.cancel"):
+                self._sweep_cancellations()
+            with span("frontend.publish") as sp:
+                sp.set_metadata(n=self._publish())
             if not self.engine.has_work() and not self._live:
                 if self._closed and not self._incoming:
                     return
@@ -273,7 +285,8 @@ class AsyncServingFrontend:
             # the event loop stays live while the engine steps: new
             # generate() calls stage work that the next iteration of
             # this loop admits between steps.
-            await loop.run_in_executor(None, self.engine.step)
+            with span("frontend.step"):
+                await loop.run_in_executor(None, self.engine.step)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -481,6 +494,7 @@ def main(argv: Optional[List[str]] = None) -> None:
           f"{wall:.2f}s; {st.steps} decode steps in "
           f"{st.megasteps} dispatches [K={engine.megastep_k}], "
           f"{st.prefills} prefills: {admit}; "
+          f"{st.prompt_tokens_fed} prompt tokens fed; "
           f"drain-wait {st.drain_wait_s:.3f}s)")
     if st.shed or st.preemptions or st.poisoned:
         print(f"overload: {st.shed} shed, {st.preemptions} "

@@ -78,12 +78,13 @@ def embed(params, tokens: jax.Array) -> jax.Array:
 
 
 def unembed(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    if cfg.tie_embeddings:
-        w = params["embedding"].T
-    else:
-        w = params["lm_head"]
-    logits = ops.matmul(x, w, out_dtype=jnp.float32)
-    return constrain(logits, ("batch", "seq", "vocab"))
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            w = params["embedding"].T
+        else:
+            w = params["lm_head"]
+        logits = ops.matmul(x, w, out_dtype=jnp.float32)
+        return constrain(logits, ("batch", "seq", "vocab"))
 
 
 # ---------------------------------------------------------------------------

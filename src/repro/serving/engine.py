@@ -58,9 +58,8 @@ on-device throughput actually collapses — on the same amortized path:
   cache family — and emits its first sampled token the substep it
   consumes the last prompt token. Decoding neighbours never stall.
   ``admission="stall"`` keeps the PR-1 behaviour: length-bucketed
-  batched prefill dispatches between megasteps (the configuration
-  ``benchmarks/serving_bench.py``'s mixed-workload sweep measures
-  losing).
+  batched prefill dispatches between megasteps, which stall every
+  decoding slot meanwhile.
 - **Per-slot sampling params**: ``temperature`` / ``top_k`` /
   ``top_p`` are SlotState fields threaded through the scanned
   ``sample_batched``, so heterogeneous requests (greedy next to
@@ -115,10 +114,39 @@ referenced partitions the pool; refcounts match table references;
 block 0 never mapped) after any step — ``serving.faults`` runs it
 after every step under chaos schedules, ``launch.serve --audit`` in
 production loops.
+
+**Tracing.** The serving loop is always instrumented with
+``jax.profiler.TraceAnnotation`` host spans, on the same clock as the
+device's ops in a profiler trace; with no profiler running each costs
+under a microsecond and records nothing. Per megastep ``N``:
+``engine.stage`` (admission arrays; ``admitted``), ``engine.dispatch``
+(the jitted megastep call; ``megastep=N``), ``engine.drain_wait``
+(blocked on block ``N``'s transfer; ``megastep=N``) and
+``engine.drain`` (``emitted``, prompt tokens ``fed``); at
+``pipeline_depth`` 1 they run in that order, at depth 2 megastep
+``N + 1``'s stage and dispatch come before ``N``'s drain wait. Per
+request: ``engine.submit`` (``uid``), ``engine.admit`` (``uid``,
+``queued_us`` since it last entered the queue) and
+``engine.first_token`` (``uid``, ``prefill_us`` from admission,
+``ttft_us`` from submit); ``Request.t_submit``/``t_admit``/
+``t_first_token`` keep the same stamps. The asyncio front end adds
+``frontend.step``, ``frontend.admit``, ``frontend.cancel`` and
+``frontend.publish`` (tokens streamed, ``n``). On the device, ``lm_head`` and ``sampler`` are
+named scopes, and the Pallas kernels are named ``quant_matmul``,
+``decode_attention`` and ``decode_attention_quant``. To capture them
+around a serving loop::
+
+    jax.profiler.start_trace("serve-trace")
+    ...  # serve
+    jax.profiler.stop_trace()
+
+then open the ``.xplane.pb`` under ``plugins/profile/`` in TensorBoard
+or XProf, or read it with ``jax.profiler.ProfileData.from_file``.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -130,7 +158,7 @@ import numpy as np
 
 from repro.models import Model
 from repro.quant.quantize import QuantizedTensor, quantize_tree
-from repro.serving.sampler import SamplingConfig, sample_batched
+from repro.serving.sampler import SamplingConfig, sample, sample_batched
 
 # Fallback K when the caller doesn't run the planner: one dispatch per
 # 8 tokens keeps Python/XLA launch overhead ≲10% for even the smallest
@@ -145,6 +173,20 @@ PHASE_PREFILL = 1   # consuming prompt tokens in-scan, no emission yet
 PHASE_DECODE = 2    # generating: sample + emit every substep
 
 _INF = float("inf")
+_GREEDY = SamplingConfig()
+
+
+@contextlib.contextmanager
+def _timed(stats, field: str, name: str, **args):
+    """A host span ``name`` (see the module docstring's Tracing
+    section) whose wall seconds are also added to ``stats.<field>``:
+    one instrumentation point per boundary, for the counter and the
+    span alike. Yields the span, for stats known only at its end
+    (``span.set_metadata(...)``)."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(name, **args) as span:
+        yield span
+    setattr(stats, field, getattr(stats, field) + time.perf_counter() - t0)
 
 
 class SubmitReject(ValueError):
@@ -204,10 +246,16 @@ class Request:
     cancelled: bool = False          # retired via ServingEngine.cancel()
     error: Optional[str] = None      # fault status (e.g. poisoned logits)
     preemptions: int = 0             # times evicted + requeued for recompute
+    # host clock (time.perf_counter) stamps: submit(), the latest
+    # placement in a slot, and the first output token drained
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first_token: Optional[float] = None
     # scheduler-internal: submission order + absolute deadline
     _seq: int = dataclasses.field(default=0, repr=False)
     _deadline_abs: Optional[float] = dataclasses.field(default=None,
                                                        repr=False)
+    _t_queued: float = dataclasses.field(default=0.0, repr=False)
 
 
 @dataclasses.dataclass
@@ -226,6 +274,8 @@ class EngineStats:
     preemptions: int = 0         # slots evicted + requeued for recompute
     shed: int = 0                # submits rejected (queue full / deadline)
     poisoned: int = 0            # requests retired on non-finite logits
+    prompt_tokens_fed: int = 0   # prompt tokens run through the model
+                                 # (prefix-cache hits are not fed)
     decode_wall_s: float = 0.0   # wall time in megastep dispatch + drain
     # pipelining attribution: where the decode wall actually goes
     stage_wall_s: float = 0.0    # host time building admission arrays
@@ -506,8 +556,9 @@ class ServingEngine:
         self._slot_reg_done: List[bool] = [False] * self.slots
         self._prefix_reg: "collections.OrderedDict[bytes, int]" = \
             collections.OrderedDict()
-        # pipelined loop: (device block, slot-occupant snapshot) per
-        # dispatched-but-undrained megastep, oldest first
+        # pipelined loop: (device block, slot-occupant snapshot,
+        # megastep ordinal) per dispatched-but-undrained megastep,
+        # oldest first
         self._inflight: Deque = collections.deque()
         # host mirror of prefill progress (from the megastep's pos row)
         self._prefill_pos: List[int] = [0] * self.slots
@@ -745,6 +796,7 @@ class ServingEngine:
     def _enqueue(self, req: Request) -> None:
         """Insert keeping the queue sorted by (deadline, submission
         order) — plain FIFO when no request carries a deadline."""
+        req._t_queued = time.perf_counter()
         key = self._edf_key(req)
         if not self.queue or self._edf_key(self.queue[-1]) <= key:
             self.queue.append(req)
@@ -969,6 +1021,11 @@ class ServingEngine:
         for prompts that can never fit, ``QueueFull`` at the
         ``max_queue`` bound, ``InfeasibleDeadline`` when
         ``req.deadline_s`` can't be met by an empty engine."""
+        req.t_submit = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.submit", uid=req.uid):
+            self._submit(req)
+
+    def _submit(self, req: Request) -> None:
         if len(np.asarray(req.prompt)) == 0:
             raise ValueError(
                 f"request {req.uid}: empty prompt — decode needs at "
@@ -1089,13 +1146,33 @@ class ServingEngine:
         return bool(self.queue) or bool(self._inflight) or \
             any(r is not None for r in self.active)
 
+    def _placed(self, req: Request) -> None:
+        """Stamp ``req``'s placement in a slot: an ``engine.admit``
+        span carrying its wait since it last entered the queue."""
+        req.t_admit = time.perf_counter()
+        with jax.profiler.TraceAnnotation(
+                "engine.admit", uid=req.uid,
+                queued_us=(req.t_admit - req._t_queued) * 1e6):
+            pass
+
+    def _first_token(self, req: Request) -> None:
+        """Stamp ``req``'s first output token reaching the host: an
+        ``engine.first_token`` span carrying admit → first token
+        (``prefill_us``) and submit → first token (``ttft_us``)."""
+        req.t_first_token = t = time.perf_counter()
+        with jax.profiler.TraceAnnotation(
+                "engine.first_token", uid=req.uid,
+                prefill_us=(t - req.t_admit) * 1e6,
+                ttft_us=(t - req.t_submit) * 1e6):
+            pass
+
     def _take_free(self) -> List:
         free = [s for s in range(self.slots) if self.active[s] is None]
         # a preempted request still riding an undrained megastep's
         # occupant snapshot must not be readmitted yet: that drain will
         # append its pre-preemption tokens, and a premature resume
         # would re-generate them (duplicated output, early retirement)
-        pending = {id(r) for _, occ in self._inflight
+        pending = {id(r) for _, occ, _ in self._inflight
                    for r in occ if r is not None}
         taken, held = [], []
         while free and self.queue:
@@ -1146,6 +1223,7 @@ class ServingEngine:
             return
         buckets: Dict[int, List] = {}
         for s, req in taken:
+            self._placed(req)
             p = self._eff_prompt(req)
             buckets.setdefault(self._bucket_len(len(p)),
                                []).append((s, req, p))
@@ -1178,9 +1256,11 @@ class ServingEngine:
             for i, (s, req, p) in enumerate(group):
                 tok = int(first[i])
                 req.output.append(tok)
+                if len(req.output) == 1:
+                    self._first_token(req)
                 self.stats.prefills += 1
                 self.stats.tokens_generated += 1
-                self._prefill_pos[s] = len(p)
+                self._feed(s, 0, len(p))
                 if tok == req.eos_id or len(req.output) >= \
                         req.max_new_tokens:
                     req.done = True       # first token already ends it
@@ -1278,6 +1358,7 @@ class ServingEngine:
             admit["top_k"][s] = topk
             admit["top_p"][s] = topp
             self.active[s] = req
+            self._placed(req)
             self._slot_prompt[s] = prompt
             self._prefill_pos[s] = start
             if temp > 0.0:
@@ -1400,7 +1481,7 @@ class ServingEngine:
             bad = (is_pre | is_dec) & ~finite
             rng, step_key = jax.random.split(st.rng)
             if all_greedy:
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = sample(logits, step_key, _GREEDY)
             else:
                 tok = sample_batched(logits, step_key, st.temperature,
                                      st.top_k, st.top_p)
@@ -1442,16 +1523,19 @@ class ServingEngine:
         ``_inflight`` (with a snapshot of its slot occupants) until
         ``_drain_oldest`` synchronizes on it. Returns False when no
         slot is live in the host view (nothing to launch)."""
-        t0 = time.perf_counter()
-        admit = self._fill_slots()
-        self.stats.stage_wall_s += time.perf_counter() - t0
+        n0 = self.stats.prefills
+        with _timed(self.stats, "stage_wall_s", "engine.stage") as span:
+            admit = self._fill_slots()
+            span.set_metadata(admitted=self.stats.prefills - n0)
         if not any(r is not None for r in self.active):
             return False
-        self.cache, self.state, block = self._megastep(
-            self.params, self.cache, self.state, admit,
-            not self._stochastic_slots)
-        self._inflight.append((block, tuple(self.active)))
-        self.stats.megasteps += 1
+        n = self.stats.megasteps + 1
+        with jax.profiler.TraceAnnotation("engine.dispatch", megastep=n):
+            self.cache, self.state, block = self._megastep(
+                self.params, self.cache, self.state, admit,
+                not self._stochastic_slots)
+        self._inflight.append((block, tuple(self.active), n))
+        self.stats.megasteps = n
         self.stats.steps += self.megastep_k
         return True
 
@@ -1463,13 +1547,29 @@ class ServingEngine:
         may have moved on (a slot freed by an earlier drain can
         already hold a newer request, whose rows in this older block
         are all idle)."""
-        block, occupants = self._inflight.popleft()
-        t0 = time.perf_counter()
-        block = np.asarray(block)        # ONE host transfer per K tokens
-        self.stats.drain_wait_s += time.perf_counter() - t0
+        block, occupants, n = self._inflight.popleft()
+        with _timed(self.stats, "drain_wait_s", "engine.drain_wait",
+                    megastep=n):
+            block = np.asarray(block)    # ONE host transfer per K tokens
+        with jax.profiler.TraceAnnotation("engine.drain") as span:
+            emitted, fed = self._absorb(block, occupants)
+            span.set_metadata(emitted=emitted, fed=fed)
+
+    def _feed(self, s: int, start: int, end: int) -> int:
+        """Slot ``s`` ran prompt tokens ``start:end`` through the model:
+        move its prompt cursor to ``end`` and count them in
+        ``stats.prompt_tokens_fed``; returns how many."""
+        self._prefill_pos[s] = end
+        self.stats.prompt_tokens_fed += end - start
+        return end - start
+
+    def _absorb(self, block: np.ndarray, occupants) -> tuple:
+        """Apply one drained block to the host view; returns (tokens
+        emitted, prompt tokens fed) by the requests still served."""
         toks, emitted = block[0], block[1].astype(bool)
         last_pos = block[2][-1]
         bad = block[3].astype(bool).any(axis=0)
+        n_emitted = n_fed = 0
         for s in range(self.slots):
             # advance the prompt-cursor mirror only while the slot
             # still serves the request this block belongs to — a stale
@@ -1479,7 +1579,8 @@ class ServingEngine:
             # publish their pages to the prefix registry.
             if (occupants[s] is not None and not bad[s]
                     and self.active[s] is occupants[s]):
-                self._prefill_pos[s] = int(last_pos[s])
+                n_fed += self._feed(s, self._prefill_pos[s],
+                                    int(last_pos[s]))
                 # prompt fully consumed → its pages now exist on
                 # device: publish them to the prefix registry
                 if (self.prefix_cache_enabled
@@ -1494,6 +1595,9 @@ class ServingEngine:
                     continue
                 tok = int(toks[k, s])
                 req.output.append(tok)
+                if len(req.output) == 1:
+                    self._first_token(req)
+                n_emitted += 1
                 self.stats.tokens_generated += 1
                 if tok == req.eos_id or len(req.output) >= \
                         req.max_new_tokens:
@@ -1525,6 +1629,7 @@ class ServingEngine:
                 self._slot_prompt[s] = None
                 if self.paged:
                     self._release_slot_blocks(s)
+        return n_emitted, n_fed
 
     def step(self) -> int:
         """Admit what fits, dispatch one megastep (up to ``megastep_k``

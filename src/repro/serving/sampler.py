@@ -37,6 +37,7 @@ class SamplingConfig:
     top_p: float = 1.0          # 1 → off
 
 
+@jax.named_scope("sampler")
 def sample_batched(logits: jax.Array, rng: jax.Array,
                    temperature: jax.Array, top_k: jax.Array,
                    top_p: jax.Array) -> jax.Array:
@@ -46,7 +47,8 @@ def sample_batched(logits: jax.Array, rng: jax.Array,
     ``top_k == 0`` / ``top_p >= 1`` disable the respective filter for
     that row. Filters apply in the same order as the static path
     (top-k, then top-p over the filtered logits) so the two flavors
-    draw identical tokens for identical parameters.
+    draw identical tokens for identical parameters. Its device ops,
+    like ``sample``'s, run in the ``sampler`` named scope.
     """
     B, V = logits.shape
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -81,7 +83,8 @@ def sample(logits: jax.Array, rng: jax.Array,
            cfg: SamplingConfig) -> jax.Array:
     """logits: (B, V) → tokens (B,). Pure/jittable (cfg is static)."""
     if cfg.temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sampler"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     B = logits.shape[0]
     return sample_batched(
         logits, rng,
