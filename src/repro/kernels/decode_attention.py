@@ -12,6 +12,12 @@ Supports part-filled caches (kv_len per batch row) and sliding-window
 caches (only the last ``window`` entries are valid, ring-buffer order
 handled by the caller via kv_len masking).
 
+The cache may be the model's layer-stacked (L, B, Hkv, S, D) buffer:
+the layer index is scalar-prefetched and the K/V ``index_map``s select
+that layer's blocks, so the decode layer scan hands the kernel the
+whole stacked cache and nothing is sliced out (a custom call's operand
+is a materialized buffer; a slice would copy the layer's cache).
+
 Grid: (B, Hkv, S/bk). Block-skip for entries beyond kv_len.
 """
 from __future__ import annotations
@@ -80,13 +86,17 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     kv_len, *, window: int = 0,
+                     kv_len, layer=0, *, window: int = 0,
                      scale: Optional[float] = None,
                      bk: int = DEFAULT_BK,
                      interpret: bool = False) -> jax.Array:
-    """q: (B, Hq, D); k, v: (B, Hkv, S, D); kv_len: (B,) int32."""
+    """q: (B, Hq, D); k, v: (B, Hkv, S, D), or the layer-stacked
+    (L, B, Hkv, S, D) with ``layer`` (int32 scalar) naming the layer
+    read; kv_len: (B,) int32."""
+    if k.ndim == 4:
+        k, v = k[None], v[None]
     B, Hq, D = q.shape
-    _, Hkv, S, _ = k.shape
+    _, _, Hkv, S, _ = k.shape
     assert Hq % Hkv == 0
     G = Hq // Hkv
     scale = scale if scale is not None else D ** -0.5
@@ -96,29 +106,37 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kv_len = jnp.asarray(kv_len, jnp.int32)
     if kv_len.ndim == 0:
         kv_len = jnp.full((B,), kv_len, jnp.int32)
+    # one scalar-prefetch array: the B lengths, then the layer
+    lens = jnp.concatenate(
+        [kv_len, jnp.asarray(layer, jnp.int32).reshape(1)])
 
     qg = q.reshape(B, Hkv, G, D)
     kernel = functools.partial(
         _decode_kernel, scale=scale, window=window, bk=bk,
         kv_steps=kv_steps, out_dtype=q.dtype)
+    kv_spec = pl.BlockSpec((pl.squeezed, 1, 1, bk, D),
+                           lambda b, h, j, n: (n[B], b, h, j, 0))
 
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, kv_steps),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),       # kv_len
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, kv_steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, D),
+                             lambda b, h, j, n: (b, h, 0, 0)),
+                kv_spec,
+                kv_spec,
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, D),
+                                   lambda b, h, j, n: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         interpret=interpret,
         name="decode_attention",
-    )(kv_len, qg, k, v)
+    )(lens, qg, k, v)
     return out.reshape(B, Hq, D)

@@ -12,7 +12,8 @@ unpack cost is VREG shifts hidden under the cache stream.
 
 Same grid and scratch layout as ``decode_attention.py``:
 (B, Hkv, S/bk), online-softmax (m, l, acc) state in VMEM scratch,
-grouped queries (G, D) per KV head. The q4_0 nibble-unpack
+grouped queries (G, D) per KV head, and the same layer-stacked cache
+operand with a scalar-prefetched layer index. The q4_0 nibble-unpack
 (shift/sign-extend) is fused into the K/V block load; dequantized
 values are rounded to bf16 before the dot so the kernel feeds the MXU
 the exact values the XLA path (``dequantize_rows`` -> bf16 view) sees.
@@ -62,8 +63,8 @@ def _dequant_rows(qblk: jax.Array, sblk: jax.Array, fmt: str) -> jax.Array:
     return (q.astype(jnp.float32) * s).astype(jnp.bfloat16)
 
 
-def _decode_quant_kernel(lens_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, fmt: str,
+def _decode_quant_kernel(lens_ref, q_ref, kq_ref, ks_ref, vq_ref,
+                         vs_ref, o_ref, m_ref, l_ref, acc_ref, *, fmt: str,
                          scale: float, window: int, bk: int,
                          kv_steps: int, out_dtype):
     b, j = pl.program_id(0), pl.program_id(2)
@@ -116,18 +117,23 @@ def _decode_quant_kernel(lens_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
 
 
 def decode_attention_quant(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
-                           v_q: jax.Array, v_scale: jax.Array, kv_len, *,
-                           fmt: str, window: int = 0,
+                           v_q: jax.Array, v_scale: jax.Array, kv_len,
+                           layer=0, *, fmt: str, window: int = 0,
                            scale: Optional[float] = None,
                            bk: int = DEFAULT_BK,
                            interpret: bool = False) -> jax.Array:
     """q: (B, Hq, D); k_q/v_q int8 payload (B, Hkv, S, D or D//2);
-    k_scale/v_scale (B, Hkv, S, D//g); kv_len: (B,) int32."""
+    k_scale/v_scale (B, Hkv, S, D//g); or all four layer-stacked with a
+    leading L, with ``layer`` (int32 scalar) naming the layer read;
+    kv_len: (B,) int32."""
     if fmt not in ("q8_0", "q4_0"):
         raise ValueError(f"decode_attention_quant: fmt must be q8_0 or "
                          f"q4_0, got {fmt!r}")
+    if k_q.ndim == 4:
+        k_q, k_scale, v_q, v_scale = (
+            a[None] for a in (k_q, k_scale, v_q, v_scale))
     B, Hq, D = q.shape
-    _, Hkv, S, Dp = k_q.shape
+    _, _, Hkv, S, Dp = k_q.shape
     if (D // 2 if fmt == "q4_0" else D) != Dp:
         raise ValueError(f"payload dim {Dp} inconsistent with head dim "
                          f"{D} under {fmt} (q {q.shape}, k_q {k_q.shape})")
@@ -140,6 +146,9 @@ def decode_attention_quant(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
     kv_len = jnp.asarray(kv_len, jnp.int32)
     if kv_len.ndim == 0:
         kv_len = jnp.full((B,), kv_len, jnp.int32)
+    # one scalar-prefetch array: the B lengths, then the layer
+    lens = jnp.concatenate(
+        [kv_len, jnp.asarray(layer, jnp.int32).reshape(1)])
 
     ng = k_scale.shape[-1]
     qg = q.reshape(B, Hkv, G, D)
@@ -147,25 +156,30 @@ def decode_attention_quant(q: jax.Array, k_q: jax.Array, k_scale: jax.Array,
         _decode_quant_kernel, fmt=fmt, scale=scale, window=window, bk=bk,
         kv_steps=kv_steps, out_dtype=q.dtype)
 
+    def cache_spec(width):
+        return pl.BlockSpec((pl.squeezed, 1, 1, bk, width),
+                            lambda b, h, j, n: (n[B], b, h, j, 0))
+
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, kv_steps),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),       # kv_len
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, Dp), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, ng), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, Dp), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, ng), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, kv_steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, D),
+                             lambda b, h, j, n: (b, h, 0, 0)),
+                cache_spec(Dp), cache_spec(ng),
+                cache_spec(Dp), cache_spec(ng),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, D),
+                                   lambda b, h, j, n: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         interpret=interpret,
         name="decode_attention_quant",
-    )(kv_len, qg, k_q, k_scale, v_q, v_scale)
+    )(lens, qg, k_q, k_scale, v_q, v_scale)
     return out.reshape(B, Hq, D)

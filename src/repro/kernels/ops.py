@@ -140,16 +140,23 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, kv_len, *,
-                     window: int = 0, use_pallas: bool = False,
+                     layer=None, window: int = 0, use_pallas: bool = False,
                      scale: Optional[float] = None) -> jax.Array:
-    """Single-token attention; q (B, H, D), cache (B, Hkv, S, D)."""
+    """Single-token attention; q (B, H, D), cache (B, Hkv, S, D), or the
+    layer-stacked (L, B, Hkv, S, D) with ``layer`` naming the layer.
+
+    The Pallas kernel reads the stacked cache in place (the layer picks
+    its blocks); the XLA fallback indexes the layer itself."""
     if use_pallas:
-        S = k.shape[2]
+        S = k.shape[-2]
         bk = _pick_tile(S, _da.DEFAULT_BK)
         if bk:
             return _da.decode_attention(
-                q, k, v, kv_len, window=window, scale=scale, bk=bk,
+                q, k, v, kv_len, 0 if layer is None else layer,
+                window=window, scale=scale, bk=bk,
                 interpret=_interpret_default())
+    if layer is not None:
+        k, v = k[layer], v[layer]
     return _decode_attention_jnp(q, k, v, kv_len, window=window,
                                  scale=scale)
 
@@ -157,12 +164,15 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, kv_len, *,
 def decode_attention_quant(q: jax.Array, k_q: jax.Array,
                            k_scale: jax.Array, v_q: jax.Array,
                            v_scale: jax.Array, kv_len, *, fmt: str,
-                           window: int = 0, use_pallas: bool = False,
+                           layer=None, window: int = 0,
+                           use_pallas: bool = False,
                            scale: Optional[float] = None) -> jax.Array:
     """Decode attention straight off quantized KV-cache leaves.
 
     q: (B, Hq, D); k_q/v_q int8 payload (B, Hkv, S, D) [q8_0] or
-    (B, Hkv, S, D//2) [q4_0]; k_scale/v_scale (B, Hkv, S, D//g).
+    (B, Hkv, S, D//2) [q4_0]; k_scale/v_scale (B, Hkv, S, D//g); or
+    all four layer-stacked with a leading L and ``layer`` naming the
+    layer.
 
     The Pallas path dequantizes in-register inside the online-softmax
     block loop — HBM reads stay at the quantized width. The XLA
@@ -171,13 +181,17 @@ def decode_attention_quant(q: jax.Array, k_q: jax.Array,
     ``_decode_attention_jnp`` on it.
     """
     if use_pallas:
-        S = k_q.shape[2]
+        S = k_q.shape[-2]
         bk = _pick_tile(S, _daq.DEFAULT_BK)
         if bk:
             return _daq.decode_attention_quant(
-                q, k_q, k_scale, v_q, v_scale, kv_len, fmt=fmt,
+                q, k_q, k_scale, v_q, v_scale, kv_len,
+                0 if layer is None else layer, fmt=fmt,
                 window=window, scale=scale, bk=bk,
                 interpret=_interpret_default())
+    if layer is not None:
+        k_q, k_scale, v_q, v_scale = (
+            a[layer] for a in (k_q, k_scale, v_q, v_scale))
     k = dequantize_rows(k_q, k_scale, fmt)
     v = dequantize_rows(v_q, v_scale, fmt)
     return _decode_attention_jnp(q, k, v, kv_len, window=window,

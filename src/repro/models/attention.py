@@ -205,21 +205,26 @@ def attention_forward(p, cfg: ModelConfig, x: jax.Array, *,
 
 
 def attention_decode(p, cfg: ModelConfig, x: jax.Array, cache: Dict, *,
-                     window: int = 0,
+                     layer=None, window: int = 0,
                      kv_override: Optional[Tuple] = None,
                      use_rope: bool = True,
                      write_mask: Optional[jax.Array] = None,
                      ) -> Tuple[jax.Array, Dict]:
     """One-token decode with functional cache update.
 
-    x: (B, 1, D); cache: {"k": (B,Hkv,S,hd), "v": ..., "lens": (B,)}.
+    x: (B, 1, D); cache: {"k": (B,Hkv,S,hd), "v": ..., "lens": (B,)},
+    or the model's layer-stacked cache (every leaf with a leading L)
+    with ``layer`` (int32 scalar) naming this layer. A stacked cache is
+    updated in place, one row per slot, and the attention kernels read
+    the stacked buffer by layer index: the decode layer scan carries one
+    cache buffer, and no layer's cache is ever copied out of it.
+
     ``lens`` counts tokens already in the cache; the new token is
     written at slot ``lens % S`` (ring buffer when the cache is a
     sliding window). Paged caches (``"block_table"`` present) route the
-    write through the slot's block table instead; ``write_mask`` (B,)
-    bool redirects non-advancing rows' writes to the reserved garbage
-    block — paged pools have no per-slot batch axis, so the frozen-write
-    select that protects dense caches cannot be applied after the fact.
+    write through the slot's block table instead. ``write_mask`` (B,)
+    bool freezes rows where it is False at the write point: their cache
+    leaves and ``lens`` stay bit-identical (see ``kv_cache_write``).
     """
     B = x.shape[0]
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -235,11 +240,13 @@ def attention_decode(p, cfg: ModelConfig, x: jax.Array, cache: Dict, *,
         out = out.reshape(B, 1, H * hd)
         return layers.linear(p["wo"], out, use_pallas=cfg.use_pallas), cache
 
-    lens = cache["lens"]                          # (B,) int32
+    at = () if layer is None else (layer,)
+    lens = cache["lens"][at]                      # (B,) int32
     paged = "block_table" in cache
-    S_cache = (cache["block_table"].shape[1] * cache["k"].shape[2]
-               if paged else cache["k"].shape[2])
+    S_cache = (cache["block_table"].shape[-1] * cache["k"].shape[-2]
+               if paged else cache["k"].shape[-2])
     kv_quant = cfg.kv_quant if "k_scale" in cache else "bf16"
+    quantized = kv_quant in ("q8_0", "q4_0")
     pos = lens                                    # new token's position
     if use_rope:
         # pin the rope operands before the cos/sin broadcast-mul: on big
@@ -257,37 +264,37 @@ def attention_decode(p, cfg: ModelConfig, x: jax.Array, cache: Dict, *,
         k = k.reshape(B, Hkv, hd)
     v = v.reshape(B, Hkv, hd)
     slot = lens % S_cache
-    new_cache = dict(cache, lens=lens + 1)
+    adv = lens + 1 if write_mask is None else jnp.where(write_mask,
+                                                         lens + 1, lens)
+    new_cache = dict(cache, lens=adv if layer is None
+                     else cache["lens"].at[layer].set(adv))
     new_cache.update(kv_cache_write(cache, k, v, slot,
                                     kv_quant=kv_quant,
                                     group=cfg.quant_group,
-                                    write_mask=write_mask))
+                                    write_mask=write_mask, layer=layer))
     kv_len = jnp.minimum(lens + 1, S_cache)
     q = constrain(q, ("batch", "heads", None))
-    if kv_quant in ("q8_0", "q4_0"):
-        # Fused-dequant path: hand the raw int8 payload + scale leaves
-        # to the kernel layer. Under kernels="pallas" the dequant runs
-        # in-register inside the block loop (no per-token full-cache
-        # unpack); the XLA fallback inside decode_attention_quant is
-        # computation-identical to the old kv_cache_read route. Paged
-        # pools gather into the dense (B,Hkv,S,·) kernel-entry shape
-        # first — positions past kv_len hold garbage-block junk that
-        # the kernels' kpos < kv_len mask never reads.
-        if paged:
-            tbl = cache["block_table"]
-            k_q = paged_gather(new_cache["k"], tbl)
-            v_q = paged_gather(new_cache["v"], tbl)
-            k_s = paged_gather(new_cache["k_scale"], tbl)
-            v_s = paged_gather(new_cache["v_scale"], tbl)
-        else:
-            k_q, v_q = new_cache["k"], new_cache["v"]
-            k_s, v_s = new_cache["k_scale"], new_cache["v_scale"]
-        out = ops.decode_attention_quant(
-            q, k_q, k_s, v_q, v_s, kv_len=kv_len,
-            fmt=kv_quant, use_pallas=cfg.use_pallas)
+    names = ("k", "k_scale", "v", "v_scale") if quantized else ("k", "v")
+    if paged:
+        # gather this layer's pool into the dense (B, Hkv, S, ·) kernel
+        # shape; positions past kv_len hold garbage-block junk that the
+        # kernels' kpos < kv_len mask never reads
+        tbl = cache["block_table"][at]
+        leaves = [paged_gather(new_cache[n][at], tbl) for n in names]
+        read_layer = None
     else:
-        k_read, v_read = kv_cache_read(new_cache, kv_quant=kv_quant)
-        out = ops.decode_attention(q, k_read, v_read, kv_len=kv_len,
+        leaves = [new_cache[n] for n in names]
+        read_layer = layer
+    if quantized:
+        # Fused-dequant path: the raw int8 payload + scale leaves go to
+        # the kernel layer, which dequantizes in-register under
+        # kernels="pallas" (no per-token full-cache unpack).
+        out = ops.decode_attention_quant(
+            q, *leaves, kv_len=kv_len, fmt=kv_quant, layer=read_layer,
+            use_pallas=cfg.use_pallas)
+    else:
+        out = ops.decode_attention(q, *leaves, kv_len=kv_len,
+                                   layer=read_layer,
                                    use_pallas=cfg.use_pallas)
     out = out.reshape(B, 1, H * hd)
     out = layers.linear(p["wo"], out, use_pallas=cfg.use_pallas)
@@ -297,8 +304,16 @@ def attention_decode(p, cfg: ModelConfig, x: jax.Array, cache: Dict, *,
 def kv_cache_write(cache: Dict, k: jax.Array, v: jax.Array,
                    slot: jax.Array, *, kv_quant: str = "bf16",
                    group: int = 32,
-                   write_mask: Optional[jax.Array] = None) -> Dict:
+                   write_mask: Optional[jax.Array] = None,
+                   layer=None) -> Dict:
     """Write one (B, Hkv, hd) K/V row at per-row ring ``slot`` (B,).
+
+    With ``layer`` the cache leaves are layer-stacked and the rows land
+    at ``[layer, b, :, slot]``, in place: nothing else of the cache is
+    touched. The scatter indexes each (slot, head) row on its own, so
+    its window is head_dim alone and the cache keeps the layout the
+    attention kernels read (a (head, head_dim) window makes XLA lay the
+    cache out heads-minor and copy all of it back for every kernel).
 
     Quantized caches (``kv_quant`` q8_0/q4_0) quantize the row at the
     write point — int8 payload into ``k``/``v``, per-(head, group)
@@ -306,56 +321,39 @@ def kv_cache_write(cache: Dict, k: jax.Array, v: jax.Array,
     cache stream shrinks to bits/16 of its bf16 footprint. Returns the
     updated leaves only (caller merges + advances ``lens``).
 
-    Paged caches scatter through the slot's row of ``block_table``:
-    position ``slot`` lands in page ``slot // P`` at in-page offset
-    ``slot % P`` of the pool block that table entry names. Rows with
-    ``write_mask`` False are redirected to the reserved garbage block 0
-    (paged pools cannot be row-selected after the fact like dense
-    caches, so freezing must happen at the write point). Dense caches
-    ignore ``write_mask`` — the caller's post-write select handles it."""
-    B = k.shape[0]
-    if "block_table" in cache:
-        P = cache["k"].shape[2]
-        bidx = jnp.arange(B)
-        blk = cache["block_table"][bidx, slot // P]
-        if write_mask is not None:
-            blk = jnp.where(write_mask, blk, 0)
-        off = slot % P
-        if kv_quant in ("bf16", "f16", "f32"):
-            return {
-                "k": cache["k"].at[blk, :, off].set(
-                    k.astype(cache["k"].dtype)),
-                "v": cache["v"].at[blk, :, off].set(
-                    v.astype(cache["v"].dtype)),
-            }
+    Rows with ``write_mask`` False are frozen here: a dense cache gets
+    the row's old value written back at its slot; a paged pool has no
+    per-slot rows, so those rows' writes go to the reserved garbage
+    block 0 instead. Paged caches write through the slot's row of
+    ``block_table``: position ``slot`` lands in page ``slot // P`` at
+    in-page offset ``slot % P`` of the pool block that entry names."""
+    B, Hkv = k.shape[:2]
+    at = () if layer is None else (layer,)
+    if kv_quant in ("bf16", "f16", "f32"):
+        rows = {"k": k, "v": v}
+    else:
         kq, ks = quantize_rows(k, kv_quant, group)
         vq, vs = quantize_rows(v, kv_quant, group)
-        return {
-            "k": cache["k"].at[blk, :, off].set(kq),
-            "v": cache["v"].at[blk, :, off].set(vq),
-            "k_scale": cache["k_scale"].at[blk, :, off].set(
-                ks.astype(cache["k_scale"].dtype)),
-            "v_scale": cache["v_scale"].at[blk, :, off].set(
-                vs.astype(cache["v_scale"].dtype)),
-        }
-    bidx = jnp.arange(B)
-    if kv_quant in ("bf16", "f16", "f32"):
-        return {
-            "k": cache["k"].at[bidx, :, slot].set(
-                k.astype(cache["k"].dtype)),
-            "v": cache["v"].at[bidx, :, slot].set(
-                v.astype(cache["v"].dtype)),
-        }
-    kq, ks = quantize_rows(k, kv_quant, group)
-    vq, vs = quantize_rows(v, kv_quant, group)
-    return {
-        "k": cache["k"].at[bidx, :, slot].set(kq),
-        "v": cache["v"].at[bidx, :, slot].set(vq),
-        "k_scale": cache["k_scale"].at[bidx, :, slot].set(
-            ks.astype(cache["k_scale"].dtype)),
-        "v_scale": cache["v_scale"].at[bidx, :, slot].set(
-            vs.astype(cache["v_scale"].dtype)),
-    }
+        rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    paged = "block_table" in cache
+    if paged:
+        P = cache["k"].shape[-2]
+        blk = cache["block_table"][at][jnp.arange(B), slot // P]
+        if write_mask is not None:
+            blk = jnp.where(write_mask, blk, 0)
+        idx = at + (blk[:, None], jnp.arange(Hkv), (slot % P)[:, None])
+    else:
+        idx = at + (jnp.arange(B)[:, None], jnp.arange(Hkv),
+                    slot[:, None])
+    out = {}
+    for name, row in rows.items():
+        leaf = cache[name]
+        row = row.astype(leaf.dtype)
+        if write_mask is not None and not paged:
+            row = jnp.where(write_mask[:, None, None], row, leaf[idx])
+        # frozen paged rows may collide in the garbage block
+        out[name] = leaf.at[idx].set(row, unique_indices=not paged)
+    return out
 
 
 def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:
@@ -376,13 +374,12 @@ def kv_cache_read(cache: Dict, *, kv_quant: str = "bf16",
 
     bf16 caches return their leaves as-is; quantized caches dequantize
     payload × scales at the read point, materializing a bf16 view.
-    The decode hot path no longer uses this for quantized caches —
-    ``attention_decode`` hands the raw leaves to
-    ``ops.decode_attention_quant`` (in-VMEM dequant under
-    kernels="pallas"); this helper remains for tests and offline
-    inspection of cache contents. Paged caches gather their pools
-    through the block table first, so the returned view is
-    shape-identical to a dense cache's."""
+    The decode path does not use this — ``attention_decode`` hands the
+    cache leaves to the kernels (in-VMEM dequant under
+    kernels="pallas"); this helper is for tests and offline inspection
+    of cache contents. Paged caches gather their pools through the
+    block table first, so the returned view is shape-identical to a
+    dense cache's."""
     if "block_table" in cache:
         tbl = cache["block_table"]
         k = paged_gather(cache["k"], tbl)
@@ -472,7 +469,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
 def kv_cache_axes(kv_quant: str = "bf16", paged: bool = False) -> Dict:
     if paged:
         # pool leaves carry the block id on axis 0 — deliberately NOT
-        # "batch": splice/merge/freeze machinery keys on the "batch"
+        # "batch": splice/merge machinery keys on the "batch"
         # axis name and must leave pools untouched.
         pool = ("kv_block", None, "kv_page", None)
         axes = {"k": pool, "v": pool,

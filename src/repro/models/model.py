@@ -372,7 +372,7 @@ class Model:
         ``page_size`` (see ``paging_effective``) to describe a paged
         cache — pool leaves then carry "kv_block"/"kv_page" instead of
         "batch"/"kv_seq", which is what keeps the batch-keyed
-        splice/merge/freeze machinery off them."""
+        splice/merge machinery off them."""
         cfg = self.cfg
         kvq = self.kv_quant_effective(kv_quant)
         if cfg.arch_type == "ssm":
@@ -579,24 +579,29 @@ class Model:
                 new_layers.append(_freeze_rows(c_new, c_l, advance_mask))
             new_cache["layers"] = new_layers
         else:
+            # The stacked cache rides the layer scan's carry: each layer
+            # writes its new rows in place and the attention kernels
+            # read the stacked buffer by layer index, so the cache stays
+            # one buffer through the step (scanning it as xs/ys would
+            # rebuild the whole stacked cache on every step).
             cross = cfg.arch_type == "audio"
 
             def body(carry, xs):
-                h = carry
+                h, c = carry
                 if cross:
-                    p_l, c_l, ck, cv = xs
+                    p_l, l, ck, cv = xs
                 else:
-                    p_l, c_l = xs
+                    p_l, l = xs
                 z = layers.rmsnorm(h, p_l["attn_norm"], cfg.norm_eps)
-                z, c_new = attn.attention_decode(p_l["attn"], cfg, z, c_l,
-                                                 write_mask=advance_mask)
-                c_new = _freeze_rows(c_new, c_l, advance_mask)
+                z, c = attn.attention_decode(p_l["attn"], cfg, z, c,
+                                             layer=l,
+                                             write_mask=advance_mask)
                 h = h + z
                 if cross:
                     z = layers.rmsnorm(h, p_l["cross_norm"], cfg.norm_eps)
                     zc, _ = attn.attention_decode(
                         p_l["cross"], cfg, z,
-                        dict(c_l, cross_lens=cache["cross_lens"]),
+                        {"cross_lens": cache["cross_lens"]},
                         kv_override=(ck, cv))
                     h = h + zc
                 z = layers.rmsnorm(h, p_l["ffn_norm"], cfg.norm_eps)
@@ -605,13 +610,15 @@ class Model:
                 else:
                     z = mlp_mod.mlp_forward(p_l["mlp"], cfg, z)
                 h = h + z
-                return h, c_new
+                return (h, c), None
 
-            xs = ((params["layers"], cache["layers"], cache["cross_k"],
+            idx = jnp.arange(cache["layers"]["lens"].shape[0],
+                             dtype=jnp.int32)
+            xs = ((params["layers"], idx, cache["cross_k"],
                    cache["cross_v"]) if cross
-                  else (params["layers"], cache["layers"]))
-            x, stacked = _layer_scan(body, x, xs, cfg.unroll_scans)
-            new_cache["layers"] = stacked
+                  else (params["layers"], idx))
+            (x, new_cache["layers"]), _ = _layer_scan(
+                body, (x, cache["layers"]), xs, cfg.unroll_scans)
 
         x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
         logits = layers.unembed(params, x, cfg)[:, 0]
@@ -670,27 +677,17 @@ def _stack_pytrees(trees):
 
 
 def _freeze_rows(c_new, c_old, mask):
-    """Length-frozen cache write mask: batch rows where ``mask`` is
-    False keep ``c_old``. Every per-layer cache leaf (k/v/lens, SSM
-    conv/state, RG-LRU conv/state) carries batch on axis 0, so one
-    broadcast select covers all families.
-
-    Paged caches are the exception: pool leaves carry the block id on
-    axis 0, not batch, so a row select cannot undo a frozen row's
-    write. Those writes were instead redirected to the garbage block
-    inside ``attention_decode`` (via ``write_mask``); here only the
-    per-slot leaves (``lens``, ``block_table``) get the batch select."""
+    """Length-frozen cache write mask for the recurrent families (ssm,
+    hybrid): batch rows where ``mask`` is False keep ``c_old``. Their
+    per-layer cache leaves (SSM conv/state, RG-LRU conv/state, local
+    attention k/v/lens) carry batch on axis 0, so one broadcast select
+    covers them all. Attention-family caches freeze at the write point
+    instead (``attention.kv_cache_write``)."""
     if mask is None:
         return c_new
     def sel(n, o):
         m = mask.reshape((mask.shape[0],) + (1,) * (n.ndim - 1))
         return jnp.where(m, n, o)
-    if isinstance(c_new, dict) and "block_table" in c_new:
-        out = dict(c_new)
-        out["lens"] = sel(c_new["lens"], c_old["lens"])
-        out["block_table"] = sel(c_new["block_table"],
-                                 c_old["block_table"])
-        return out
     return jax.tree_util.tree_map(sel, c_new, c_old)
 
 

@@ -2,29 +2,40 @@
 v5e chip, at published model widths, with the tiles ``kernels/ops.py``
 picks. Nothing runs: a compile here proves only that Mosaic accepts the
 kernel (layouts, shifts, VMEM budget), which interpret mode cannot show.
+The serving megastep is compiled too, at a small size, to guard what its
+optimized HLO must not hold.
 
 The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import get_config
+from repro.configs import get_config, reduced
 from repro.kernels import decode_attention as _da
 from repro.kernels import decode_attention_quant as _daq
 from repro.kernels import ops
 from repro.kernels import quant_matmul as _qmm
+from repro.models import Model
 from repro.quant import QuantizedTensor, kv_group_size
+from repro.serving import ServingEngine
 
 MODELS = ("llama3.2-1b", "deepseek-7b")
 BATCH = 4          # decode slots: the GEMV M and the attention batch
 CACHE_LEN = 1024   # cached positions per slot
 GROUP = 32
+# attention kernels: one layer's cache, or the model's layer-stacked
+# cache read by a scalar-prefetched layer index (all of deepseek-7b's)
+ATTN = [("llama3.2-1b", False), ("deepseek-7b", False),
+        ("deepseek-7b", True)]
+ATTN_IDS = ["llama3.2-1b", "deepseek-7b", "deepseek-7b-stacked"]
 
 
 @pytest.fixture(scope="module")
@@ -84,39 +95,95 @@ def test_quant_matmul_compiles(arch, fmt, one_chip):
         assert "tpu_custom_call" in text
 
 
-def _attn_shapes(arch, one_chip, payload_dim=None, dtype=jnp.bfloat16):
-    """Query, one cache leaf and the lengths, at the model's widths."""
+def _attn_shapes(arch, one_chip, payload_dim=None, dtype=jnp.bfloat16,
+                 stacked=False):
+    """Query, one cache leaf, the lengths and the layer index, at the
+    model's widths; a stacked leaf holds every layer's cache."""
     cfg = get_config(arch)
     B, Hq, Hkv, D = BATCH, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    lead = (cfg.num_layers,) if stacked else ()
     q = jax.ShapeDtypeStruct((B, Hq, D), jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((B, Hkv, CACHE_LEN, payload_dim or D), dtype,
-                              sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(lead + (B, Hkv, CACHE_LEN, payload_dim or D),
+                              dtype, sharding=one_chip)
     lens = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
-    return q, kv, lens
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return q, kv, lens, layer
 
 
-@pytest.mark.parametrize("arch", MODELS)
-def test_decode_attention_compiles(arch, one_chip):
-    q, kv, lens = _attn_shapes(arch, one_chip)
+@pytest.mark.parametrize("arch,stacked", ATTN, ids=ATTN_IDS)
+def test_decode_attention_compiles(arch, stacked, one_chip):
+    q, kv, lens, layer = _attn_shapes(arch, one_chip, stacked=stacked)
     bk = ops._pick_tile(CACHE_LEN, _da.DEFAULT_BK)
     text = _compiled_text(
-        lambda q, k, v, n: _da.decode_attention(q, k, v, n, bk=bk),
-        q, kv, kv, lens)
+        lambda q, k, v, n, l: _da.decode_attention(q, k, v, n, l, bk=bk),
+        q, kv, kv, lens, layer)
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("arch", MODELS)
+@pytest.mark.parametrize("arch,stacked", ATTN, ids=ATTN_IDS)
 @pytest.mark.parametrize("fmt", ["q8_0", "q4_0"])
-def test_decode_attention_quant_compiles(arch, fmt, one_chip):
+def test_decode_attention_quant_compiles(arch, stacked, fmt, one_chip):
     D = get_config(arch).head_dim
-    q, payload, lens = _attn_shapes(
-        arch, one_chip, D // 2 if fmt == "q4_0" else D, jnp.int8)
+    q, payload, lens, layer = _attn_shapes(
+        arch, one_chip, D // 2 if fmt == "q4_0" else D, jnp.int8,
+        stacked=stacked)
     groups = D // kv_group_size(D, GROUP, fmt)
     scales = jax.ShapeDtypeStruct(payload.shape[:-1] + (groups,),
                                   jnp.bfloat16, sharding=one_chip)
     bk = ops._pick_tile(CACHE_LEN, _daq.DEFAULT_BK)
     text = _compiled_text(
-        lambda q, k, ks, v, vs, n: _daq.decode_attention_quant(
-            q, k, ks, v, vs, n, fmt=fmt, bk=bk),
-        q, payload, scales, payload, scales, lens)
+        lambda q, k, ks, v, vs, n, l: _daq.decode_attention_quant(
+            q, k, ks, v, vs, n, l, fmt=fmt, bk=bk),
+        q, payload, scales, payload, scales, lens, layer)
     assert "tpu_custom_call" in text
+
+
+def test_megastep_keeps_the_cache_in_one_buffer(one_chip, monkeypatch):
+    """The megastep's optimized HLO holds no copy of the whole stacked K
+    or V cache, and no write into it (scatter or dynamic-update-slice)
+    wider than one new row per slot: the cache rides the layer scan's
+    carry, each substep writes only its new rows, and the attention
+    kernel reads the stacked buffer by layer index (a layout the row
+    writes disagree with shows up as copies; scanning the cache as
+    xs/ys, as whole-layer writes)."""
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg = reduced(get_config("deepseek-7b"), head_dim=128,
+                  kernels="pallas")
+    model = Model(cfg)
+    eng = ServingEngine(model, model.abstract_params(), slots=BATCH,
+                        max_len=256, megastep_k=4, donate_carries=True)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    args = [on_chip(a) for a in (eng.params, eng.cache, eng.state,
+                                 eng._empty_admit())]
+    text = eng._megastep.lower(*args, False).compile().as_text()
+    assert "tpu_custom_call" in text
+    shape = eng.cache["layers"]["k"].shape
+    stacked = "bf16[%s]" % ",".join(map(str, shape))
+    rows = shape[1] * shape[2] * shape[4]     # one new row per slot
+    # instruction name -> (result type, opcode, operand names)
+    hlo = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (.*?) ([\w-]+)\(([^)]*)\)",
+                     line)
+        if m:
+            hlo[m.group(1)] = (m.group(2), m.group(3),
+                               re.findall(r"%[\w.-]+", m.group(4)))
+
+    def elements(name):
+        dims = re.match(r"\w+\[([\d,]*)\]", hlo[name][0]).group(1)
+        return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+    update = {"dynamic-update-slice": 1, "scatter": 2}  # its operand
+    into = [(name, op, args) for name, (ty, op, args) in hlo.items()
+            if stacked in ty]
+    writes = [n for n, op, a in into if op in update]
+    whole = [n for n, op, a in into
+             if op in ("copy", "copy-start", "copy-done")
+             or (op in update and elements(a[update[op]]) > rows)]
+    assert not whole, whole
+    assert writes, "no row write into the stacked cache found"

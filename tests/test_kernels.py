@@ -1,4 +1,6 @@
 """Per-kernel interpret-mode allclose sweeps vs the ref.py oracles."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -333,6 +335,40 @@ def test_decode_attention_quant_parity(case, fmt):
                                       use_pallas=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=5e-5)
+
+
+@pytest.mark.parametrize("fmt", ["bf16", "q8_0", "q4_0"])
+@pytest.mark.parametrize("layer", [1, 2], ids=["middle", "last"])
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel", "xla"])
+def test_decode_attention_reads_stacked_cache_by_layer(fmt, layer,
+                                                       use_pallas):
+    """The decode kernels (and their XLA fallbacks) called on the
+    layer-stacked (L, B, Hkv, S, D) cache with a layer index equal the
+    call on that layer's own (B, Hkv, S, D) cache, bit for bit."""
+    from repro.kernels import ops
+    from repro.quant import quantize_rows
+    L, B, Hq, Hkv, S, D = 3, 2, 8, 2, 128, 64
+    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+    q = jax.random.normal(ks[0], (B, Hq, D)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (L, B, Hkv, S, D)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (L, B, Hkv, S, D)).astype(jnp.bfloat16)
+    lens = jnp.asarray([S // 2, S], jnp.int32)
+    if fmt == "bf16":
+        leaves = (k, v)
+        call = functools.partial(ops.decode_attention,
+                                 use_pallas=use_pallas)
+    else:
+        kq, ksc = quantize_rows(k, fmt, 32)
+        vq, vsc = quantize_rows(v, fmt, 32)
+        leaves = (kq, ksc, vq, vsc)
+        call = functools.partial(ops.decode_attention_quant, fmt=fmt,
+                                 use_pallas=use_pallas)
+    got = jax.jit(lambda l, *a: call(q, *a, kv_len=lens, layer=l))(
+        jnp.int32(layer), *leaves)
+    want = jax.jit(lambda *a: call(q, *a, kv_len=lens))(
+        *(a[layer] for a in leaves))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_decode_attention_quant_kv_len_zero_rows():

@@ -650,3 +650,74 @@ def test_reference_decode_at_engine_width_matches_engine(slots):
     for r in reqs:
         assert r.output == m.reference_decode(params, r.prompt, 8,
                                               batch=slots), r.uid
+
+
+# cache layouts the attention families decode through: (kv format,
+# sliding window, page size)
+FREEZE_CASES = {"bf16": ("bf16", 0, 0), "q8_0": ("q8_0", 0, 0),
+                "ring": ("bf16", 16, 0), "paged": ("bf16", 0, 8)}
+
+
+@pytest.mark.parametrize("case", sorted(FREEZE_CASES))
+def test_decode_step_frozen_rows_stay_bit_identical(case):
+    """``decode_step`` with a mixed ``advance_mask``: rows where it is
+    False keep every cache leaf and ``lens`` bit-identical, and
+    advancing rows (cache and logits) equal what a step that advances
+    every row gives them — the old write-then-select path's result, as
+    rows never mix. Caches hold real rows first; the ring has wrapped.
+    A paged pool is compared through each slot's block table (frozen
+    rows' writes land in the garbage block, which no table maps)."""
+    from repro.models import attention as attn
+    kv, window, page = FREEZE_CASES[case]
+    cfg = reduced(get_config("deepseek-7b"), kv_quant=kv,
+                  sliding_window=window)
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    B, max_len = 4, 32
+    cache = m.init_cache(B, max_len, page_size=page)
+    lay = cache["layers"]
+    if page:
+        L, _, n_pages = lay["block_table"].shape
+        tbl = 1 + jnp.arange(B * n_pages, dtype=jnp.int32)
+        lay["block_table"] = jnp.broadcast_to(tbl.reshape(B, n_pages),
+                                              (L, B, n_pages))
+    step = jax.jit(m.decode_step)
+    rng = np.random.default_rng(0)
+
+    def tokens():
+        return jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 1)),
+                           jnp.int32)
+
+    for _ in range(20):
+        _, cache = step(params, tokens(), cache, jnp.ones(B, bool))
+    mask = np.array([False, True, True, False])
+    tok = tokens()
+    got_logits, got = step(params, tok, cache, jnp.asarray(mask))
+    all_logits, every = step(params, tok, cache, jnp.ones(B, bool))
+    np.testing.assert_array_equal(np.asarray(got_logits)[mask],
+                                  np.asarray(all_logits)[mask])
+
+    def rows(c):
+        """Each leaf with the slot on axis 1: (L, B, ...)."""
+        c = c["layers"]
+        if not page:
+            return c
+        out = {n: c[n] for n in ("lens", "block_table")}
+        for n in ("k", "v"):
+            out[n] = jnp.stack([attn.paged_gather(c[n][l],
+                                                  c["block_table"][l])
+                                for l in range(c[n].shape[0])])
+        return out
+
+    before, got, every = rows(cache), rows(got), rows(every)
+    assert set(got) >= {"k", "v", "lens"}
+    for name in got:
+        g = np.asarray(got[name])
+        np.testing.assert_array_equal(g[:, ~mask],
+                                      np.asarray(before[name])[:, ~mask],
+                                      err_msg=name)
+        np.testing.assert_array_equal(g[:, mask],
+                                      np.asarray(every[name])[:, mask],
+                                      err_msg=name)
+    assert (np.asarray(got["lens"])[:, mask]
+            == np.asarray(before["lens"])[:, mask] + 1).all()
