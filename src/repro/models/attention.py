@@ -321,6 +321,13 @@ def kv_cache_write(cache: Dict, k: jax.Array, v: jax.Array,
     cache stream shrinks to bits/16 of its bf16 footprint. Returns the
     updated leaves only (caller merges + advances ``lens``).
 
+    The scale rows of a dense cache are written by one
+    ``dynamic_update_slice`` per slot instead: they are narrower than a
+    lane tile (4 scales a head of 128 in groups of 32), and a scatter
+    into such a leaf makes XLA lay it out sequence-minor and copy all of
+    it back around every kernel call (six whole-leaf copies a layer at
+    40 layers, one slot and 8192 positions).
+
     Rows with ``write_mask`` False are frozen here: a dense cache gets
     the row's old value written back at its slot; a paged pool has no
     per-slot rows, so those rows' writes go to the reserved garbage
@@ -349,10 +356,21 @@ def kv_cache_write(cache: Dict, k: jax.Array, v: jax.Array,
     for name, row in rows.items():
         leaf = cache[name]
         row = row.astype(leaf.dtype)
-        if write_mask is not None and not paged:
-            row = jnp.where(write_mask[:, None, None], row, leaf[idx])
-        # frozen paged rows may collide in the garbage block
-        out[name] = leaf.at[idx].set(row, unique_indices=not paged)
+        if paged or not name.endswith("_scale"):
+            if write_mask is not None and not paged:
+                row = jnp.where(write_mask[:, None, None], row, leaf[idx])
+            # frozen paged rows may collide in the garbage block
+            out[name] = leaf.at[idx].set(row, unique_indices=not paged)
+            continue
+        shape = (1,) * (len(at) + 1) + (Hkv, 1, row.shape[-1])
+        for b in range(B):
+            start = at + (b, 0, slot[b], 0)
+            new = row[b].reshape(shape)
+            if write_mask is not None:
+                new = jnp.where(write_mask[b], new,
+                                jax.lax.dynamic_slice(leaf, start, shape))
+            leaf = jax.lax.dynamic_update_slice(leaf, new, start)
+        out[name] = leaf
     return out
 
 

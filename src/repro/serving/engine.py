@@ -122,7 +122,9 @@ under a microsecond and records nothing. Per megastep ``N``:
 ``engine.stage`` (admission arrays; ``admitted``), ``engine.dispatch``
 (the jitted megastep call; ``megastep=N``), ``engine.drain_wait``
 (blocked on block ``N``'s transfer; ``megastep=N``) and
-``engine.drain`` (``emitted``, prompt tokens ``fed``); at
+``engine.drain`` (``emitted``, prompt tokens ``fed``, and the live
+cache positions the substeps' attention read, ``attended``, summed
+over substeps and slots); at
 ``pipeline_depth`` 1 they run in that order, at depth 2 megastep
 ``N + 1``'s stage and dispatch come before ``N``'s drain wait. Per
 request: ``engine.submit`` (``uid``), ``engine.admit`` (``uid``,
@@ -1552,8 +1554,8 @@ class ServingEngine:
                     megastep=n):
             block = np.asarray(block)    # ONE host transfer per K tokens
         with jax.profiler.TraceAnnotation("engine.drain") as span:
-            emitted, fed = self._absorb(block, occupants)
-            span.set_metadata(emitted=emitted, fed=fed)
+            emitted, fed, attended = self._absorb(block, occupants)
+            span.set_metadata(emitted=emitted, fed=fed, attended=attended)
 
     def _feed(self, s: int, start: int, end: int) -> int:
         """Slot ``s`` ran prompt tokens ``start:end`` through the model:
@@ -1563,12 +1565,25 @@ class ServingEngine:
         self.stats.prompt_tokens_fed += end - start
         return end - start
 
+    def _cache_len(self, s: int, req: Request) -> int:
+        """Positions ``req`` holds in slot ``s``'s cache, in the host
+        view: the prompt fed so far, then the prompt and every emitted
+        token but the last (which the next substep feeds)."""
+        if req.output:
+            return len(req.prompt) + len(req.output) - 1
+        return self._prefill_pos[s] if self.active[s] is req else 0
+
     def _absorb(self, block: np.ndarray, occupants) -> tuple:
         """Apply one drained block to the host view; returns (tokens
-        emitted, prompt tokens fed) by the requests still served."""
+        emitted, prompt tokens fed, cache positions attended) by the
+        requests still served. A slot that grows from ``n`` to
+        ``n + a`` positions attended ``n + 1, ..., n + a`` of them on
+        its ``a`` substeps."""
         toks, emitted = block[0], block[1].astype(bool)
         last_pos = block[2][-1]
         bad = block[3].astype(bool).any(axis=0)
+        held = {s: self._cache_len(s, r) for s, r in enumerate(occupants)
+                if r is not None}
         n_emitted = n_fed = 0
         for s in range(self.slots):
             # advance the prompt-cursor mirror only while the slot
@@ -1629,7 +1644,11 @@ class ServingEngine:
                 self._slot_prompt[s] = None
                 if self.paged:
                     self._release_slot_blocks(s)
-        return n_emitted, n_fed
+        n_attended = 0
+        for s, n in held.items():
+            a = max(self._cache_len(s, occupants[s]) - n, 0)
+            n_attended += a * n + a * (a + 1) // 2
+        return n_emitted, n_fed, n_attended
 
     def step(self) -> int:
         """Admit what fits, dispatch one megastep (up to ``megastep_k``

@@ -27,15 +27,21 @@ from repro.models import Model
 from repro.quant import QuantizedTensor, kv_group_size
 from repro.serving import ServingEngine
 
-MODELS = ("llama3.2-1b", "deepseek-7b")
+MODELS = ("llama3.2-1b", "deepseek-7b", "mistral-nemo-12b")
 BATCH = 4          # decode slots: the GEMV M and the attention batch
 CACHE_LEN = 1024   # cached positions per slot
 GROUP = 32
 # attention kernels: one layer's cache, or the model's layer-stacked
-# cache read by a scalar-prefetched layer index (all of deepseek-7b's)
-ATTN = [("llama3.2-1b", False), ("deepseek-7b", False),
-        ("deepseek-7b", True)]
-ATTN_IDS = ["llama3.2-1b", "deepseek-7b", "deepseek-7b-stacked"]
+# cache read by a scalar-prefetched layer index (all of deepseek-7b's or
+# mistral-nemo-12b's 40 layers, GQA 4:1), at BATCH slots of CACHE_LEN;
+# the last case at the solo cell's one slot of 8192 positions
+ATTN = [("llama3.2-1b", False, BATCH, CACHE_LEN),
+        ("deepseek-7b", False, BATCH, CACHE_LEN),
+        ("deepseek-7b", True, BATCH, CACHE_LEN),
+        ("mistral-nemo-12b", True, BATCH, CACHE_LEN),
+        ("mistral-nemo-12b", True, 1, 8192)]
+ATTN_IDS = ["llama3.2-1b", "deepseek-7b", "deepseek-7b-stacked",
+            "mistral-nemo-12b-stacked", "mistral-nemo-12b-stacked-solo"]
 
 
 @pytest.fixture(scope="module")
@@ -96,41 +102,46 @@ def test_quant_matmul_compiles(arch, fmt, one_chip):
 
 
 def _attn_shapes(arch, one_chip, payload_dim=None, dtype=jnp.bfloat16,
-                 stacked=False):
+                 stacked=False, batch=BATCH, cache_len=CACHE_LEN):
     """Query, one cache leaf, the lengths and the layer index, at the
     model's widths; a stacked leaf holds every layer's cache."""
     cfg = get_config(arch)
-    B, Hq, Hkv, D = BATCH, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    B, Hq, Hkv, D = batch, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     lead = (cfg.num_layers,) if stacked else ()
     q = jax.ShapeDtypeStruct((B, Hq, D), jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct(lead + (B, Hkv, CACHE_LEN, payload_dim or D),
+    kv = jax.ShapeDtypeStruct(lead + (B, Hkv, cache_len, payload_dim or D),
                               dtype, sharding=one_chip)
     lens = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
     layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     return q, kv, lens, layer
 
 
-@pytest.mark.parametrize("arch,stacked", ATTN, ids=ATTN_IDS)
-def test_decode_attention_compiles(arch, stacked, one_chip):
-    q, kv, lens, layer = _attn_shapes(arch, one_chip, stacked=stacked)
-    bk = ops._pick_tile(CACHE_LEN, _da.DEFAULT_BK)
+@pytest.mark.parametrize("arch,stacked,batch,cache_len", ATTN,
+                         ids=ATTN_IDS)
+def test_decode_attention_compiles(arch, stacked, batch, cache_len,
+                                   one_chip):
+    q, kv, lens, layer = _attn_shapes(arch, one_chip, stacked=stacked,
+                                      batch=batch, cache_len=cache_len)
+    bk = ops._pick_tile(cache_len, _da.DEFAULT_BK)
     text = _compiled_text(
         lambda q, k, v, n, l: _da.decode_attention(q, k, v, n, l, bk=bk),
         q, kv, kv, lens, layer)
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("arch,stacked", ATTN, ids=ATTN_IDS)
+@pytest.mark.parametrize("arch,stacked,batch,cache_len", ATTN,
+                         ids=ATTN_IDS)
 @pytest.mark.parametrize("fmt", ["q8_0", "q4_0"])
-def test_decode_attention_quant_compiles(arch, stacked, fmt, one_chip):
+def test_decode_attention_quant_compiles(arch, stacked, batch, cache_len,
+                                         fmt, one_chip):
     D = get_config(arch).head_dim
     q, payload, lens, layer = _attn_shapes(
         arch, one_chip, D // 2 if fmt == "q4_0" else D, jnp.int8,
-        stacked=stacked)
+        stacked=stacked, batch=batch, cache_len=cache_len)
     groups = D // kv_group_size(D, GROUP, fmt)
     scales = jax.ShapeDtypeStruct(payload.shape[:-1] + (groups,),
                                   jnp.bfloat16, sharding=one_chip)
-    bk = ops._pick_tile(CACHE_LEN, _daq.DEFAULT_BK)
+    bk = ops._pick_tile(cache_len, _daq.DEFAULT_BK)
     text = _compiled_text(
         lambda q, k, ks, v, vs, n, l: _daq.decode_attention_quant(
             q, k, ks, v, vs, n, l, fmt=fmt, bk=bk),
@@ -187,3 +198,48 @@ def test_megastep_keeps_the_cache_in_one_buffer(one_chip, monkeypatch):
              or (op in update and elements(a[update[op]]) > rows)]
     assert not whole, whole
     assert writes, "no row write into the stacked cache found"
+
+
+@pytest.mark.parametrize("slots,max_len", [(1, 8192), (BATCH, 2048)],
+                         ids=["one-slot-8192", "4-slots-2048"])
+def test_megastep_writes_quantized_cache_rows_in_place(one_chip, monkeypatch,
+                                                       slots, max_len):
+    """With a q8_0 cache the layer scan copies no stacked cache leaf,
+    payload or scale: the scale leaves' rows (4 groups a head, narrower
+    than a lane tile) are written in place, and the kernel reads them
+    where they are. The program's entry may re-lay a scale leaf once a
+    megastep, outside the scan. All 40 of mistral-nemo-12b's layers:
+    XLA re-lays a scattered scale leaf only when it is this large."""
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg = reduced(get_config("mistral-nemo-12b"), num_layers=40,
+                  head_dim=128, kernels="pallas", quant_policy="q4_0",
+                  kv_quant="q8_0")
+    model = Model(cfg)
+    eng = ServingEngine(model, model.abstract_params(), slots=slots,
+                        max_len=max_len, megastep_k=4, donate_carries=True,
+                        quant_policy="q4_0", kv_quant="q8_0")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    args = [on_chip(a) for a in (eng.params, eng.cache, eng.state,
+                                 eng._empty_admit())]
+    text = eng._megastep.lower(*args, True).compile().as_text()
+    assert "tpu_custom_call" in text
+    leaves = eng.cache["layers"]
+    stacked = {"%s[%s]" % ({"int8": "s8", "bfloat16": "bf16"}[str(a.dtype)],
+                           ",".join(map(str, a.shape)))
+               for n, a in leaves.items() if n != "lens"}
+    assert len(stacked) == 2               # payload and scale shapes
+    copies, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%\S+ \(", line)
+        if head:
+            comp = "entry" if head.group(1) else "inner"
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])\S* "
+                     r"(copy|copy-start)\(", line)
+        if m and m.group(1) in stacked and comp == "inner":
+            copies.append(line.strip()[:120])
+    assert not copies, copies

@@ -176,6 +176,16 @@ def test_counters_match_the_tokens_served(served):
     assert eng.stats.stage_wall_s > 0 and eng.stats.drain_wait_s > 0
 
 
+def test_drain_counts_every_live_position_attended(served):
+    """A request that ends holding ``n`` cache positions (its prompt and
+    every output but the last) attended 1, 2, ..., n of them, one
+    substep each."""
+    _, reqs, streamed, spans = served
+    held = [len(r.prompt) + len(streamed[r.uid]) - 1 for r in reqs]
+    assert sum(s[3]["attended"] for s in _named(spans, "engine.drain")) \
+        == sum(n * (n + 1) // 2 for n in held)
+
+
 def test_prompt_tokens_fed_on_the_stall_path():
     cfg = _cfg()
     model = Model(cfg)
