@@ -109,10 +109,11 @@ def _kernel_checks():
     for K, N in ((cfg.d_model, cfg.d_ff), (cfg.d_ff, cfg.d_model)):
         x = jax.random.normal(next(keys), (KERNEL_BATCH, K)).astype(bf16)
         wf = jax.random.normal(next(keys), (K, N), jnp.float32)
-        bn = ops._pick_lane_tile(N, _qmm.DEFAULT_BN)
-        bk = ops._pick_lane_tile(K, _qmm.DEFAULT_BK, multiple=32)
         for quant in (quantize_q8_0, quantize_q4_0):
-            def run(x=x, w=quant(wf), bn=bn, bk=bk, K=K, N=N):
+            w = quant(wf)
+            _, bn, bk = ops._quant_tiles(KERNEL_BATCH, K, N, w)   # served
+
+            def run(x=x, w=w, bn=bn, bk=bk, K=K, N=N):
                 got = _qmm.quant_matmul(x, w, bm=KERNEL_BATCH, bn=bn, bk=bk,
                                         out_dtype=jnp.float32)
                 want = oracle(ref.quant_matmul_ref, x, w,

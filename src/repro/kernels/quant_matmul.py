@@ -11,11 +11,28 @@ measures on the A17's DRAM bus.
 Tiling: grid (M/bm, N/bn, K/bk), K innermost so the f32 accumulator
 tile lives in VMEM scratch across the K loop. ``bk`` must be a multiple
 of the quant group (32). Lane alignment is *not* assumed here: the
-dispatch layer (``ops.matmul`` → ``_pick_lane_tile``) enforces that the
+dispatch layer (``ops.matmul`` → ``_quant_tiles``) enforces that the
 lane dims bn/bk are 128-aligned or span their whole dimension, and the
 sublane dim bm is 8-aligned when M >= 8 (bm = M below that — Mosaic
 pads sublanes for small decode GEMVs); shapes with no such tiling fall
 back to the XLA dequant path instead of reaching this kernel.
+
+q4_0 without an interleave: the stored format packs logical row 2i in
+the low nibble of packed row i and row 2i+1 in the high one
+(``pack_int4``; unchanged here), so
+
+    x @ W  =  x[:, 0::2] @ lo  +  x[:, 1::2] @ hi.
+
+A 32-row quant group is 16 packed rows of each plane under the same
+scale row, so each plane dequantizes where it lies with the scales as
+stored. The wrapper reorders the activation once instead of the weight:
+inside every K-block of ``bk`` columns the even columns come first, then
+the odd ones, so the kernel pairs the first ``bk/2`` columns of its
+activation block with the low plane and the last ``bk/2`` with the high
+plane — two dots into one f32 accumulator. The call keeps its three
+operands (``bf16[M, K]``, ``s8[K/2, N]``, the scales). The half-block
+activation slice is a lane slice, free where ``bk/2`` is a multiple of
+128; ``ops._quant_k_tile`` holds the q4_0 tile rule.
 """
 from __future__ import annotations
 
@@ -34,19 +51,12 @@ DEFAULT_BN = 256
 DEFAULT_BK = 512
 
 
-def _dequant_block_q8(qblk, sblk, group):
+def _dequant_rows(qblk, sblk, rows):
+    """Scale each run of ``rows`` rows of an integer tile by its row of
+    ``sblk``, in f32."""
     bk, bn = qblk.shape
-    q = qblk.astype(jnp.float32).reshape(bk // group, group, bn)
+    q = qblk.astype(jnp.float32).reshape(bk // rows, rows, bn)
     return (q * sblk.astype(jnp.float32)[:, None, :]).reshape(bk, bn)
-
-
-def _dequant_block_q4(qblk, sblk, group):
-    # qblk packed: (bk//2, bn) int8, two nibbles per byte
-    lo, hi = int4_nibbles(qblk)
-    k2, bn = qblk.shape
-    q = jnp.stack([lo, hi], axis=1).reshape(2 * k2, bn)  # interleaved
-    q = q.astype(jnp.float32).reshape(2 * k2 // group, group, bn)
-    return (q * sblk.astype(jnp.float32)[:, None, :]).reshape(2 * k2, bn)
 
 
 def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, fmt: str,
@@ -56,17 +66,28 @@ def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, fmt: str,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]
-    if fmt == "q8_0":
-        w = _dequant_block_q8(q_ref[...], s_ref[...], group)
-    else:
-        w = _dequant_block_q4(q_ref[...], s_ref[...], group)
     # Round the dequantized tile to the activation dtype and feed the
     # MXU an activation-dtype x activation-dtype dot with f32
-    # accumulation — the exact op ops.matmul's XLA fallback runs on
-    # dequantize(w, out_dtype), so the backends are token-identical
-    # (not merely allclose) for bf16 serving.
-    acc_ref[...] += jax.lax.dot(x, w.astype(x.dtype),
-                                preferred_element_type=jnp.float32)
+    # accumulation — the op ops.matmul's XLA fallback runs on
+    # dequantize(w, out_dtype), on the same rounded operands. Only the
+    # f32 summation order over K differs (per block, and for q4_0 per
+    # half block), so a bf16 output can differ from the fallback's by
+    # one rounding step, rarely.
+    if fmt == "q8_0":
+        w = _dequant_rows(q_ref[...], s_ref[...], group)
+        acc_ref[...] += jax.lax.dot(x, w.astype(x.dtype),
+                                    preferred_element_type=jnp.float32)
+    else:
+        # Each nibble plane meets its own half of the reordered block.
+        lo, hi = int4_nibbles(q_ref[...])
+        s = s_ref[...]
+        h = lo.shape[0]
+        lo_w = _dequant_rows(lo, s, group // 2).astype(x.dtype)
+        hi_w = _dequant_rows(hi, s, group // 2).astype(x.dtype)
+        acc_ref[...] += (
+            jax.lax.dot(x[:, :h], lo_w, preferred_element_type=jnp.float32)
+            + jax.lax.dot(x[:, h:], hi_w,
+                          preferred_element_type=jnp.float32))
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _store():
@@ -106,6 +127,9 @@ def quant_matmul(x: jax.Array, w: QuantizedTensor, *,
     k_steps = K // bk
     packed = w.fmt == "q4_0"
     kdiv = 2 if packed else 1
+    if packed:
+        # even columns of each K-block first, then its odd ones
+        x = x.reshape(M, k_steps, bk // 2, 2).swapaxes(-1, -2).reshape(M, K)
 
     kernel = functools.partial(
         _qmm_kernel, fmt=w.fmt, group=group, k_steps=k_steps,

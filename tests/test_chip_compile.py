@@ -9,8 +9,10 @@ The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import json
 import os
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,7 @@ MODELS = ("llama3.2-1b", "deepseek-7b", "mistral-nemo-12b")
 BATCH = 4          # decode slots: the GEMV M and the attention batch
 CACHE_LEN = 1024   # cached positions per slot
 GROUP = 32
+ROOT = Path(__file__).resolve().parents[1]
 # attention kernels: one layer's cache, or the model's layer-stacked
 # cache read by a scalar-prefetched layer index (all of deepseek-7b's or
 # mistral-nemo-12b's 40 layers, GQA 4:1), at BATCH slots of CACHE_LEN;
@@ -79,12 +82,23 @@ def _matmul_shapes(arch):
     return [(cfg.d_model, cfg.d_ff), (cfg.d_ff, cfg.d_model)]
 
 
+def _operand_text(compiled):
+    """The optimized HLO with each operand's shape printed beside it, as
+    the op events of a chip trace carry them."""
+    from jax._src.lib import xla_client as xc
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    return compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+
+
 @pytest.mark.parametrize("arch", MODELS)
 @pytest.mark.parametrize("fmt", ["q8_0", "q4_0"])
 def test_quant_matmul_compiles(arch, fmt, one_chip):
+    rule = json.loads((ROOT / "perfbench" / "kernels.json").read_text())
+    match = re.compile(rule["kernels"]["quant_matmul"]["match"])
     for K, N in _matmul_shapes(arch):
         bn = ops._pick_lane_tile(N, _qmm.DEFAULT_BN)
-        bk = ops._pick_lane_tile(K, _qmm.DEFAULT_BK, multiple=GROUP)
+        bk = ops._quant_k_tile(K, fmt, GROUP)
         assert bn and bk, (K, N)     # the fused path, not the fallback
         rows = K // 2 if fmt == "q4_0" else K
 
@@ -92,13 +106,46 @@ def test_quant_matmul_compiles(arch, fmt, one_chip):
             w = QuantizedTensor(data, scales, fmt, GROUP)
             return _qmm.quant_matmul(x, w, bm=BATCH, bn=bn, bk=bk)
 
-        text = _compiled_text(
-            f,
+        text = _operand_text(jax.jit(f).lower(
             jax.ShapeDtypeStruct((BATCH, K), jnp.bfloat16, sharding=one_chip),
             jax.ShapeDtypeStruct((rows, N), jnp.int8, sharding=one_chip),
             jax.ShapeDtypeStruct((K // GROUP, N), jnp.bfloat16,
-                                 sharding=one_chip))
+                                 sharding=one_chip)).compile())
         assert "tpu_custom_call" in text
+        # one call of three operands, as the benchmark's rule reads it:
+        # the activation bf16[M, K] beside the payload s8[K/2, N] (q4_0)
+        # or s8[K, N] (q8_0)
+        call = match.search(text)
+        assert call, (K, N)
+        shapes = re.findall(r"(bf16|s8)\[(\d+),(\d+)\]", call.group(0))
+        assert shapes == [("bf16", str(BATCH), str(K)),
+                          ("s8", str(rows), str(N)),
+                          ("bf16", str(K // GROUP), str(N))], shapes
+
+
+@pytest.mark.parametrize("K,N", [(64, 128), (128, 64), (640, 256)])
+def test_quant_matmul_q4_0_full_span_compiles(K, N, one_chip):
+    """Narrow q4_0 widths (the CPU suite's test models) take one
+    full-span K block whose activation halves are not 128-aligned lane
+    slices; Mosaic must still compile them, or those GEMVs would need
+    the XLA fallback."""
+    w_shape = QuantizedTensor(jnp.zeros((K // 2, N), jnp.int8),
+                              jnp.zeros((K // GROUP, N), jnp.bfloat16),
+                              "q4_0", GROUP)
+    bm, bn, bk = ops._quant_tiles(BATCH, K, N, w_shape)
+    assert bk == K and (bk // 2) % ops.LANE, bk    # the unaligned case
+
+    def f(x, data, scales):
+        w = QuantizedTensor(data, scales, "q4_0", GROUP)
+        return _qmm.quant_matmul(x, w, bm=bm, bn=bn, bk=bk)
+
+    text = _compiled_text(
+        f,
+        jax.ShapeDtypeStruct((BATCH, K), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((K // 2, N), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((K // GROUP, N), jnp.bfloat16,
+                             sharding=one_chip))
+    assert "tpu_custom_call" in text
 
 
 def _attn_shapes(arch, one_chip, payload_dim=None, dtype=jnp.bfloat16,

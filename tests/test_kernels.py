@@ -11,7 +11,7 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.quant_matmul import quant_matmul
-from repro.quant import quantize_q4_0, quantize_q8_0
+from repro.quant import dequantize, quantize_q4_0, quantize_q8_0
 
 
 # ---------------------------------------------------------------------------
@@ -84,6 +84,28 @@ def test_quant_matmul_stacked_layer_slices(quant):
                             interpret=True, out_dtype=jnp.float32)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("m", [1, 8])
+@pytest.mark.parametrize("bk", [256, 512, 1024])
+@pytest.mark.parametrize("parity", [0, 1])
+def test_quant_matmul_q4_0_nibble_meets_its_column(parity, bk, m):
+    """q4_0 pairs the low nibble plane with the even activation columns
+    of a K-block and the high plane with the odd ones. With one non-zero
+    logical row k the output is exactly x[:, k] * w[k, :]: a swap of the
+    planes or a permutation inside a block picks another column of x."""
+    K, N = 1024, 256
+    kx, kw = jax.random.split(jax.random.PRNGKey(41))
+    x = jax.random.normal(kx, (m, K), jnp.float32).astype(jnp.bfloat16)
+    row = jax.random.normal(kw, (N,), jnp.float32)
+    for k in (parity, 130 + parity, 516 + parity, K - 2 + parity):
+        w = quantize_q4_0(jnp.zeros((K, N), jnp.float32).at[k].set(row))
+        out = quant_matmul(x, w, bm=m, bn=N, bk=bk, interpret=True,
+                           out_dtype=jnp.float32)
+        w_k = dequantize(w, jnp.bfloat16)[k].astype(jnp.float32)
+        want = x[:, k].astype(jnp.float32)[:, None] * w_k[None, :]
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want),
+                                      err_msg=f"row {k}")
 
 
 def test_quant_matmul_grid_tiling_exact():
@@ -477,6 +499,8 @@ def test_matmul_dispatch_misaligned_shapes(mkn, quant):
         w = quant(wf, group=K)   # degenerate group for tiny K
     else:
         w = quant(wf)
+    # only the decode GEMV has a fused tiling
+    assert bool(ops._quant_tiles(M, K, N, w)) == (mkn == (1, 64, 64))
     out = ops.matmul(x, w, use_pallas=True, out_dtype=jnp.float32)
     want = ref.quant_matmul_ref(x, w, out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),

@@ -33,8 +33,9 @@ import numpy as np
 from _hypothesis_compat import given, settings, st
 
 from repro.configs import get_config, reduced
+from repro.kernels import ops
 from repro.models import Model
-from repro.quant import quantize_tree
+from repro.quant import QuantizedTensor, quantize_tree
 from repro.serving import (Request, SamplingConfig, ServingEngine,
                            sample, sample_batched)
 
@@ -335,6 +336,14 @@ def test_pallas_engine_matches_reference(seed, quant, k):
     rng = np.random.default_rng(seed)
     kv = "bf16" if quant == "bf16" else quant
     cfg, m, params = _model("deepseek-7b", quant, kv)
+    # every quantized layer GEMV takes the fused kernel, not the XLA
+    # fallback (which would compare XLA with XLA)
+    for w in jax.tree_util.tree_leaves(
+            params["layers"],
+            is_leaf=lambda l: isinstance(l, QuantizedTensor)):
+        if isinstance(w, QuantizedTensor):
+            K, N = w.logical_shape[-2:]
+            assert ops._quant_tiles(1, K, N, w), (quant, K, N)
     for mode in ("chunked", "stall"):
         reqs = _random_requests(cfg, rng, 2, max_prompt=8, max_new_hi=6)
         eng = _engine("deepseek-7b", 2, k, mode, quant, kv,
