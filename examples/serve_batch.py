@@ -100,9 +100,7 @@ def main() -> None:
     # them. And when the dense slots*max_len prealloc overshoots what
     # is actually live: the pool only holds pages in use. It costs a
     # per-step block-table gather, so for short-context streams with
-    # no reuse, dense (--page-size 0) is the right default —
-    # dispatch.plan(prefix_hit_rate=...) makes the same call from the
-    # analytic twin (scheduler.simulate_paging).
+    # no reuse, dense (--page-size 0) is the right default.
     ap.add_argument("--page-size", type=int, default=0,
                     help="KV-cache page size in tokens; 0 = dense "
                          "(see note above on when paging pays)")

@@ -3,12 +3,6 @@
 One ``ModelConfig`` dataclass covers all six assigned architecture
 families (dense / moe / ssm / hybrid / audio / vlm). Family-specific
 fields default to "off" so a dense config never sees MoE or SSM state.
-
-The execution-strategy knobs (``fuse_qkv``, ``fuse_gate_up``,
-``quant_policy``, ``scheduler_version``) are the paper's contribution
-surfaced as first-class config: they select between the paper's V0
-(serial, unfused), V1 (graph-level fusion), V2 (fusion + tensor
-parallelism) and V3 (cross-axis split — the regression case).
 """
 from __future__ import annotations
 
@@ -24,13 +18,6 @@ ARCH_TYPES = ("dense", "moe", "ssm", "hybrid", "audio", "vlm")
 
 # Paper precisions: F16 baseline, Q8_0 and Q4_0 k-quant analogues.
 PRECISIONS = ("f32", "bf16", "f16", "q8_0", "q4_0")
-
-# Paper §7 execution versions, adapted to TPU (see DESIGN.md §2).
-#   v0: serial, no fusion          (paper baseline, 11.5 tk/s)
-#   v1: graph-level fusion         (fused qkv / gate-up, 13 tk/s)
-#   v2: v1 + tensor parallelism    (fused GEMMs sharded on `model`, 15 tk/s)
-#   v3: cross-axis split           (attention/FFN on different axes, 6 tk/s)
-SCHEDULER_VERSIONS = ("v0", "v1", "v2", "v3")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,10 +77,7 @@ class ModelConfig:
     # --- multimodal stubs ------------------------------------------------
     num_prefix_embeddings: int = 0  # VLM patch embeddings prepended (stub)
 
-    # --- execution strategy (the paper's technique) ----------------------
-    scheduler_version: str = "v2"  # v0/v1/v2/v3 — see SCHEDULER_VERSIONS
-    fuse_qkv: bool = True          # derived from scheduler_version unless forced
-    fuse_gate_up: bool = True
+    # --- execution strategy ----------------------------------------------
     quant_policy: str = "bf16"     # weights precision: bf16|q8_0|q4_0
     quant_group: int = 32          # k-quant group size along reduction dim
     # KV-cache precision (the other half of the decode bandwidth story:
@@ -124,13 +108,8 @@ class ModelConfig:
     # -------------------------------------------------------------------
     def __post_init__(self):
         assert self.arch_type in ARCH_TYPES, self.arch_type
-        assert self.scheduler_version in SCHEDULER_VERSIONS
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
-        # scheduler version drives fusion flags unless caller overrode them
-        if self.scheduler_version == "v0":
-            object.__setattr__(self, "fuse_qkv", False)
-            object.__setattr__(self, "fuse_gate_up", False)
         # kernels is the one public switch; reconcile with the legacy
         # use_pallas bool (kernels wins when set, derives otherwise)
         if self.kernels == "":

@@ -1,9 +1,8 @@
 """The paper's own evaluated models (Table/Fig 4 of the paper).
 
 These are the six models the study benchmarks on the iPhone 15 Pro.
-They are used by the paper-faithful reproduction benchmarks
-(``benchmarks/fig4_throughput.py`` etc.) and as small end-to-end demo
-models; llama3.2-1b is the paper's primary profiling target (§6).
+They serve as small end-to-end demo models; llama3.2-1b is the paper's
+primary profiling target (§6) and the model ``chip_smoke.py`` runs.
 """
 from repro.configs.base import ModelConfig
 

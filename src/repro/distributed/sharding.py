@@ -7,8 +7,7 @@ sets encode the paper's §7 execution versions on a pod (DESIGN.md §2):
 - ``v0``/``v1`` — no tensor parallelism (the paper's CPU-threads-only
   configurations). Weights are FSDP-sharded on ``data`` only so that
   compile-time memory still fits; the ``model`` axis carries sequence
-  sharding only. v0 additionally disables GEMM fusion (a model-level
-  flag, not a sharding concern).
+  sharding only. The two share one rule set.
 - ``v2`` — fusion + tensor parallelism: Megatron column/row sharding on
   ``model``, FSDP on ``data``, batch on (``pod``, ``data``). The
   production default.
@@ -16,7 +15,7 @@ sets encode the paper's §7 execution versions on a pod (DESIGN.md §2):
   block are deliberately sharded on *different* mesh axes, so GSPMD must
   reshard the residual stream at every block boundary. This reproduces,
   structurally, the CPU+GPU split that dropped throughput from 15 to
-  6 tk/s (collective term explodes — see benchmarks/scheduler_versions).
+  6 tk/s: the collective term explodes.
 """
 from __future__ import annotations
 
@@ -128,7 +127,7 @@ RULES_TP2D = AxisRules("tp2d", {
     "expert": ("data", "model"),
 })
 
-# Hillclimb iteration 2 for decode (tp2d was refuted — see
+# Iteration 2 for decode (tp2d was refuted — see
 # EXPERIMENTS.md §Perf): classic 1-D Megatron TP on `model` only, no
 # FSDP dim. Weights replicate across `data`; affordable only when
 # quantized (q4_0: 110B x 0.5625B / 16 = 3.9 GB/chip), which is exactly
@@ -150,17 +149,17 @@ RULES_TP1D = AxisRules("tp1d", {
     "expert": "model",
 })
 
-# v2 with experts sharded over BOTH mesh axes (hillclimb experiment:
-# kimi-k2 has 384 experts = 1.5/chip at 256 chips; the token buffer
-# then reshards once data->expert instead of scatter across model while
-# batch-sharded on data).
+# v2 with experts sharded over BOTH mesh axes (kimi-k2 has 384 experts
+# = 1.5/chip at 256 chips; the token buffer then reshards once
+# data->expert instead of scatter across model while batch-sharded on
+# data).
 RULES_V2E = AxisRules("v2e", {
     **RULES_V2.rules,
     "expert": ("data", "model"),
     "expert_cap": None,
 })
 
-# Hillclimb: v2 without sequence parallelism (activations replicated on
+# v2 without sequence parallelism (activations replicated on
 # seq). Tests whether the train-shape collective term is dominated by
 # the seq@model <-> heads@model residual resharding per block.
 RULES_V2NS = AxisRules("v2ns", {**RULES_V2.rules, "seq": None})

@@ -1,9 +1,9 @@
 """Public jit'd kernel wrappers — the dispatch point between Pallas and XLA.
 
-Models call these; the paper's hardware-aware planner (core/dispatch)
-decides per-GEMM whether the Pallas path runs. Off a TPU, Pallas
-executes in interpret mode; on a TPU the same call sites always compile
-to Mosaic (see ``_interpret_default``).
+Models call these with ``use_pallas`` read from ``cfg.kernels``
+("pallas" or "xla"): that switch decides whether the Pallas path runs.
+Off a TPU, Pallas executes in interpret mode; on a TPU the same call
+sites always compile to Mosaic (see ``_interpret_default``).
 """
 from __future__ import annotations
 

@@ -39,7 +39,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config
 from repro.configs.base import InputShape, ModelConfig
-from repro.core import cost_model as cm
 from repro.distributed import context as dctx
 from repro.distributed.sharding import (
     AxisRules, logical_to_spec, rules_for, tree_shardings)
@@ -49,6 +48,12 @@ from repro.models import Model, input_specs
 from repro.models.params import abstract_params, param_pspecs
 from repro.training import AdamWConfig, TrainConfig, make_train_step
 from repro.training import optimizer as opt_mod
+
+# TPU v5e, per chip: bf16 peak FLOP/s, HBM bytes/s, one ICI link's
+# bytes/s.
+V5E_PEAK_FLOPS = 197e12
+V5E_HBM_BW = 819e9
+V5E_ICI_LINK_BW = 50e9
 
 
 # ---------------------------------------------------------------------------
@@ -158,8 +163,8 @@ def _compile_probe_subprocess(cfg: ModelConfig, shape: InputShape,
 
     Certain probe configs (unrolled MQA attention over a sharded 32k
     sequence) hit a pathological SPMD partitioner corner and compile for
-    >30 min; a subprocess lets us bound that and fall back to the
-    analytic graph estimate instead of hanging the sweep.
+    >30 min; a subprocess lets us bound that and keep the full compile's
+    own scan-counted figures instead of hanging the sweep.
     """
     import subprocess
     overrides = {f.name: getattr(cfg, f.name)
@@ -191,23 +196,6 @@ def _compile_probe_subprocess(cfg: ModelConfig, shape: InputShape,
         if line.startswith("STATS::"):
             return json.loads(line[len("STATS::"):])
     raise RuntimeError(f"probe failed: {proc.stderr[-500:]}")
-
-
-def _analytic_fallback(cfg: ModelConfig, shape: InputShape,
-                       chips: int) -> Dict[str, float]:
-    """Graph-model estimate used when calibration probes time out."""
-    from repro.core.graph import build_decoder_graph
-    seq = 1 if shape.kind == "decode" else shape.seq_len
-    kv = shape.seq_len if shape.kind == "decode" else 0
-    g = build_decoder_graph(cfg, seq=seq, kv_len=kv,
-                            batch=shape.global_batch, fused=True)
-    mult = 3.0 if shape.kind == "train" else 1.0
-    if shape.kind == "train" and cfg.remat:
-        mult = 4.0
-    return {"hlo_flops": g.total_flops * mult / chips,
-            "hlo_bytes": g.total_bytes * mult / chips,
-            "collective_bytes": float("nan"),
-            "calibration_fallback": "analytic-graph-model"}
 
 
 def _calibrated_cost(cfg: ModelConfig, shape: InputShape, mesh, rules
@@ -264,15 +252,26 @@ def _calibrated_cost(cfg: ModelConfig, shape: InputShape, mesh, rules
     return out
 
 
+def roofline(hlo_flops: float, hlo_bytes: float, collective_bytes: float,
+             chips: int) -> Dict:
+    """The three roofline terms of one step, in seconds per chip, from
+    the compile's per-device FLOPs and bytes at v5e's peaks. The dry run
+    compiles one step, so it has no host dispatch term (0)."""
+    terms = {"compute": hlo_flops / V5E_PEAK_FLOPS,
+             "memory": hlo_bytes / V5E_HBM_BW,
+             "collective": collective_bytes / V5E_ICI_LINK_BW}
+    return {**{f"{k}_s": t for k, t in terms.items()}, "dispatch_s": 0.0,
+            "hlo_flops": hlo_flops, "hlo_bytes": hlo_bytes,
+            "collective_bytes": collective_bytes, "chips": chips,
+            "dominant": max(terms, key=terms.get)}
+
+
 def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             rules_version: str = "v2",
             overrides: Optional[Dict] = None,
             calibrate: bool = True,
             verbose: bool = True) -> Dict:
     cfg = get_config(arch, **(overrides or {}))
-    from repro.configs.base import SCHEDULER_VERSIONS
-    if rules_version in SCHEDULER_VERSIONS:
-        cfg = dataclasses.replace(cfg, scheduler_version=rules_version)
     shape = INPUT_SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     rules = rules_for(rules_version)
@@ -288,18 +287,12 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     raw = {k: stats[k] for k in ("hlo_flops", "hlo_bytes",
                                  "collective_bytes")}
     if calibrate:
+        stats["raw_scan_counts"] = raw
         try:
-            cal = _calibrated_cost(cfg, shape, mesh, rules)
-            stats.update(cal)
-            stats["raw_scan_counts"] = raw
+            stats.update(_calibrated_cost(cfg, shape, mesh, rules))
         except Exception as e:  # noqa: BLE001
+            # the scan-counted figures stay (a loop body counted once)
             stats["calibration_error"] = f"{type(e).__name__}: {e}"
-            fb = _analytic_fallback(cfg, shape, mesh.size)
-            # keep the (undercounted) scan-measured collectives — the
-            # analytic graph has no collective model
-            fb["collective_bytes"] = raw["collective_bytes"]
-            stats.update(fb)
-            stats["raw_scan_counts"] = raw
 
     n_tokens = (shape.global_batch * shape.seq_len
                 if shape.kind == "train" else
@@ -309,8 +302,8 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     n_active = cfg.param_count(active_only=True)
     # MODEL_FLOPS: 6·N·D for training (fwd+bwd), 2·N·D for inference
     mf = (6.0 if shape.kind == "train" else 2.0) * n_active * n_tokens
-    terms = cm.roofline(stats["hlo_flops"], stats["hlo_bytes"],
-                        stats["collective_bytes"], chips)
+    terms = roofline(stats["hlo_flops"], stats["hlo_bytes"],
+                     stats["collective_bytes"], chips)
     result = {
         "arch": arch,
         "shape": shape_name,
@@ -328,7 +321,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         "useful_flop_ratio": (mf / chips) / stats["hlo_flops"]
             if stats["hlo_flops"] else 0.0,
         **stats,
-        "roofline": terms.as_dict(),
+        "roofline": terms,
     }
     if verbose:
         print(json.dumps({k: v for k, v in result.items()

@@ -334,9 +334,7 @@ def build_parser() -> argparse.ArgumentParser:
     # cache skips re-prefilling shared pages) or when the dense
     # slots*max_len prealloc overshoots live tokens; it costs a
     # per-step gather of the block table, so leave it off for
-    # short-context, no-reuse streams. dispatch.plan's page_size knob
-    # (fed by scheduler.simulate_paging) makes the same call
-    # analytically.
+    # short-context, no-reuse streams.
     ap.add_argument("--page-size", type=int, default=0,
                     help="KV-cache page size in tokens; 0 = dense "
                          "slot-major cache (no paging)")

@@ -13,8 +13,9 @@ Two attention paths:
   TPU hot path, selected by ``cfg.use_pallas`` for single-shard or
   shard_map execution; validated against the same oracle.
 
-The fused-QKV projection is the paper's V1 graph-parallelism realized
-as one wide GEMM (DESIGN.md §2).
+Self-attention projects q|k|v in one wide GEMM (``wqkv``);
+cross-attention keeps separate ``wq``/``wk``/``wv``, because its K and V
+come from the encoder output.
 """
 from __future__ import annotations
 
@@ -40,7 +41,7 @@ from repro.quant.quantize import (dequantize_rows, kv_group_size,
 def attention_specs(cfg: ModelConfig, cross: bool = False) -> Dict:
     D, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
     specs: Dict = {}
-    if cfg.fuse_qkv and not cross:
+    if not cross:
         specs["wqkv"] = layers.linear_spec(
             D, qd + 2 * kvd, ("embed", "qkv_fused"), bias=cfg.qkv_bias,
             bias_axis="qkv_fused")
@@ -150,18 +151,15 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 def _split_qkv(cfg: ModelConfig, p, x, use_pallas: bool):
+    """q, k, v of ``x``; cross-attention (no ``wqkv``) projects q alone
+    and returns None for k and v, which its caller reads from the
+    encoder."""
+    if "wqkv" not in p:
+        return layers.linear(p["wq"], x, use_pallas=use_pallas), None, None
     qd, kvd = cfg.q_dim, cfg.kv_dim
-    if "wqkv" in p:
-        qkv = layers.linear(p["wqkv"], x, use_pallas=use_pallas)
-        qkv = constrain(qkv, ("batch", None, "qkv_fused"))
-        q = qkv[..., :qd]
-        k = qkv[..., qd:qd + kvd]
-        v = qkv[..., qd + kvd:]
-    else:
-        q = layers.linear(p["wq"], x, use_pallas=use_pallas)
-        k = layers.linear(p["wk"], x, use_pallas=use_pallas)
-        v = layers.linear(p["wv"], x, use_pallas=use_pallas)
-    return q, k, v
+    qkv = layers.linear(p["wqkv"], x, use_pallas=use_pallas)
+    qkv = constrain(qkv, ("batch", None, "qkv_fused"))
+    return qkv[..., :qd], qkv[..., qd:qd + kvd], qkv[..., qd + kvd:]
 
 
 def attention_forward(p, cfg: ModelConfig, x: jax.Array, *,

@@ -1,10 +1,8 @@
 """Shared building blocks: norms, RoPE, embeddings, (fused) projections.
 
-The fused projections are the paper's §7 technique in TPU form: the
-independent GEMM sets found by ``core/scheduler.find_concurrent_gemms``
-({Q,K,V}, {ffn_gate, ffn_up}, the SSD in_proj pieces) become single wide
-matmuls — one MXU launch instead of three, one weight stream instead of
-three strided ones.
+Projections that read the same input are fused into one wide matmul
+({Q,K,V}, {ffn_gate, ffn_up}, the SSD in_proj pieces): one MXU launch
+and one weight stream instead of three strided ones.
 """
 from __future__ import annotations
 

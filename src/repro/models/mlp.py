@@ -1,9 +1,9 @@
 """FFN block — the paper's dominant GEMM cost center (Fig 6).
 
-``fuse_gate_up`` concatenates the two independent SwiGLU projections
-into one GEMM (paper V1 graph-parallelism on TPU). Column/row Megatron
-sharding comes from the logical axes: gate/up are column-parallel on
-``mlp``, down is row-parallel back to ``embed``.
+A gated MLP runs its gate and up projections as one GEMM
+(``w_gate_up``, gate then up); a plain MLP has ``w_up`` alone.
+Column/row Megatron sharding comes from the logical axes: gate/up are
+column-parallel on ``mlp``, down is row-parallel back to ``embed``.
 """
 from __future__ import annotations
 
@@ -22,12 +22,7 @@ def mlp_specs(cfg: ModelConfig) -> Dict:
     D, F = cfg.d_model, cfg.d_ff
     specs: Dict = {}
     if cfg.glu:
-        if cfg.fuse_gate_up:
-            specs["w_gate_up"] = layers.linear_spec(D, 2 * F,
-                                                    ("embed", "mlp"))
-        else:
-            specs["w_gate"] = layers.linear_spec(D, F, ("embed", "mlp"))
-            specs["w_up"] = layers.linear_spec(D, F, ("embed", "mlp"))
+        specs["w_gate_up"] = layers.linear_spec(D, 2 * F, ("embed", "mlp"))
     else:
         specs["w_up"] = layers.linear_spec(D, F, ("embed", "mlp"))
     specs["w_down"] = layers.linear_spec(F, D, ("mlp", "embed"))
@@ -38,13 +33,9 @@ def mlp_forward(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     act = layers.activation_fn(cfg.activation)
     up_kw = dict(use_pallas=cfg.use_pallas)
     if cfg.glu:
-        if "w_gate_up" in p:
-            gu = layers.linear(p["w_gate_up"], x, **up_kw)
-            gu = constrain(gu, ("batch", None, "mlp"))
-            g, u = jnp.split(gu, 2, axis=-1)
-        else:
-            g = layers.linear(p["w_gate"], x, **up_kw)
-            u = layers.linear(p["w_up"], x, **up_kw)
+        gu = layers.linear(p["w_gate_up"], x, **up_kw)
+        gu = constrain(gu, ("batch", None, "mlp"))
+        g, u = jnp.split(gu, 2, axis=-1)
         h = act(g) * u
     else:
         h = act(layers.linear(p["w_up"], x, **up_kw))

@@ -69,14 +69,11 @@ on-device throughput actually collapses — on the same amortized path:
   SlotState pytrees are donated into the megastep and prefill jits
   (``donate_argnums``), so XLA updates the multi-MB KV/state carry in
   place instead of writing a second copy — halving the carry's HBM
-  traffic at each dispatch boundary. ``core.cost_model.megastep_time``
-  accounts the same term analytically.
+  traffic at each dispatch boundary.
 
 EOS/length retirement stays in-scan via the length-frozen cache write
 mask (``decode_step(advance_mask=...)``), so finished slots emit pad
-tokens without corrupting their cache. ``core.dispatch.plan`` picks K
-(and the admission mode) from the same dispatch-overhead napkin math
-the paper's §6 model uses to predict the CPU win.
+tokens without corrupting their cache.
 
 **Failure semantics (overload + poisoned requests).** On-device
 serving lives permanently near its resource ceiling, so running out is
@@ -162,9 +159,8 @@ from repro.models import Model
 from repro.quant.quantize import QuantizedTensor, quantize_tree
 from repro.serving.sampler import SamplingConfig, sample, sample_batched
 
-# Fallback K when the caller doesn't run the planner: one dispatch per
-# 8 tokens keeps Python/XLA launch overhead ≲10% for even the smallest
-# models we serve (see core.dispatch.choose_megastep_k).
+# K when the caller gives none: one dispatch per 8 tokens keeps
+# Python/XLA launch overhead ≲10% for even the smallest models we serve.
 DEFAULT_MEGASTEP_K = 8
 
 PAD_ID = 0
@@ -443,8 +439,8 @@ class ServingEngine:
         # donating a still-pending computation's output buffer makes
         # the next jit call run inline, serializing exactly the
         # dispatch the pipelining exists to overlap. Warn + override
-        # rather than raise so planner-free callers still get a
-        # working (and faster) configuration.
+        # rather than raise so callers still get a working (and
+        # faster) configuration.
         if self.pipeline_depth > 1 and self.donate_carries:
             warnings.warn(
                 "pipeline_depth>1 with donate_carries=True serializes "
@@ -585,9 +581,7 @@ class ServingEngine:
 
     def cache_nbytes(self) -> int:
         """Device bytes of the live cache pytree (int8 payload + scale
-        leaves for quantized caches) — the measured counterpart of the
-        analytic ``cost_model.decode_carry_bytes`` / bits-per-16 ratio
-        the kv-precision bench reports."""
+        leaves for quantized caches)."""
         return sum(l.size * l.dtype.itemsize
                    for l in jax.tree_util.tree_leaves(self.cache))
 

@@ -416,44 +416,3 @@ def test_parser_overload_knobs():
     args = build_parser().parse_args(["--max-queue", "8", "--audit"])
     assert args.max_queue == 8 and args.audit is True
 
-
-# -- analytic twin ---------------------------------------------------------
-
-def test_simulate_overload_bounded_beats_unbounded_past_capacity():
-    from repro.core import simulate_overload
-    cfg = get_config("deepseek-7b")
-    ov = simulate_overload(cfg, slots=4, prompt_len=16, max_new=16,
-                           page_size=8, cache_blocks=9)
-    cap = ov["capacity"]
-    assert cap["capacity_rps"] > 0
-    assert cap["drain_s_per_request"] == pytest.approx(
-        1.0 / cap["capacity_rps"])
-    sweep = ov["sweep"]
-    for m_, pt in sweep.items():
-        b, u = pt["bounded"], pt["unbounded"]
-        assert u["shed_rate"] == 0.0
-        if m_ <= 1.0:
-            assert b["shed_rate"] == 0.0
-        else:
-            # past capacity: shedding holds goodput, unbounded decays
-            assert b["shed_rate"] > 0.0
-            assert b["goodput_tok_s"] > u["goodput_tok_s"]
-    # shed rate grows with arrival rate
-    sheds = [sweep[m_]["bounded"]["shed_rate"] for m_ in sorted(sweep)]
-    assert sheds == sorted(sheds)
-
-
-def test_plan_emits_queue_bound_only_past_capacity():
-    from repro.configs.base import InputShape
-    from repro.core import plan
-    cfg = get_config("deepseek-7b")
-    sh = InputShape("decode_s", 64, 4, "decode")
-    hot = plan(cfg, sh, arrival_rate_per_s=1000.0, avg_prompt_len=16,
-               max_new=16)
-    assert hot.max_queue > 0
-    cold = plan(cfg, sh, arrival_rate_per_s=1e-4, avg_prompt_len=16,
-                max_new=16)
-    assert cold.max_queue == 0
-    if hot.page_size:
-        assert hot.cache_blocks > 0
-    assert "max_queue" in hot.summary()

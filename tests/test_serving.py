@@ -267,83 +267,8 @@ def test_engine_rejects_mismatched_prequantized_params():
     assert req.output == m.reference_decode(q8, req.prompt, 3)
 
 
-def test_planner_picks_megastep_k():
-    """Dispatch-overhead napkin math: K grows as the device step
-    shrinks relative to the launch cost, the analytic serving model
-    predicts the amortization win, and mixed-load admission planning
-    picks chunked admission exactly when stalls cost more than riding."""
-    from repro.core import (a17_cpu, choose_megastep_k, megastep_time,
-                            simulate_admission, simulate_megastep)
-    hw = a17_cpu(2)
-    assert choose_megastep_k(hw, step_s=1.0) == 1       # step ≫ dispatch
-    assert choose_megastep_k(hw, step_s=1e-5) > 1       # dispatch-bound
-    assert choose_megastep_k(hw, step_s=0.0) == 1
-    # mixed load: frequent arrivals cap K (admission waits on the scan)
-    k_idle = choose_megastep_k(hw, step_s=1e-5)
-    k_busy = choose_megastep_k(hw, step_s=1e-5, arrival_rate_per_s=1e4)
-    assert 1 <= k_busy < k_idle
-
-    ks = (1, 4, 8, 16)
-    from repro.configs.paper_models import PAPER_MODELS
-    import dataclasses as dc
-    llama = PAPER_MODELS["llama3.2-1b"]
-    fast = dc.replace(hw, dispatch_overhead_s=5e-3)     # dispatch-bound
-    r = simulate_megastep(llama, fast, ks=ks)
-    tps = [r[k].tokens_per_s for k in ks]
-    assert tps == sorted(tps) and tps[-1] > tps[0]
-
-    # donated carries: the un-donated boundary copy costs throughput
-    t_d = megastep_time(1e-4, hw, 8, carry_bytes=1e9, donate_carries=True)
-    t_n = megastep_time(1e-4, hw, 8, carry_bytes=1e9,
-                        donate_carries=False)
-    assert t_d < t_n
-    r_nd = simulate_megastep(llama, fast, ks=(8,), donate_carries=False)
-    assert r_nd[8].tokens_per_s < r[8].tokens_per_s
-
-    # admission planning: dispatch-dominated admission-heavy traffic
-    # (short prompts, unbatched stalls, short generations) → chunked
-    # wins; cheap dispatch + very long prompts + perfect bucketing →
-    # stall wins (one fused prefill pass beats 4096 rider substeps)
-    heavy = dc.replace(hw, dispatch_overhead_s=5e-2)
-    adm = simulate_admission(llama, heavy, k=8, batch=8, prompt_len=4,
-                             max_new=8, prefill_bucket=1)
-    assert adm["chunked"].tokens_per_s > 1.1 * adm["stall"].tokens_per_s
-    cheap = dc.replace(hw, dispatch_overhead_s=1e-7)
-    adm2 = simulate_admission(llama, cheap, k=8, batch=4,
-                              prompt_len=4096, max_new=8,
-                              prefill_bucket=4)
-    assert adm2["stall"].tokens_per_s > adm2["chunked"].tokens_per_s
-
-
-def test_plan_decode_sets_admission_and_donation():
-    """The hardware-aware plan carries the serving-loop decisions."""
-    from repro.core import TPU_V5E, plan
-    from repro.configs.base import INPUT_SHAPES
-    cfg = get_config("deepseek-7b")
-    p = plan(cfg, INPUT_SHAPES["decode_32k"], TPU_V5E,
-             avg_prompt_len=32)
-    assert p.megastep_k >= 1
-    assert p.admission in ("chunked", "stall")
-    # donation pairs with depth: a pipelined plan must NOT donate (the
-    # previous carry is still in flight when the next megastep wants
-    # the buffer), a depth-1 plan always should
-    assert p.donate_carries == (p.pipeline_depth < 2)
-    assert "admission=" in p.summary()
-    # precision is a first-class plan output: memory-bound decode on
-    # TPU wants the 4.5-bit stream; the quality floor can veto it
-    assert p.quant_policy == "q4_0"
-    assert "quant=" in p.summary()
-    assert p.config_overrides()["quant_policy"] == "q4_0"
-    p_q8 = plan(cfg, INPUT_SHAPES["decode_32k"], TPU_V5E,
-                avg_prompt_len=32, quality_floor_bits=8.0)
-    assert p_q8.quant_policy == "q8_0"
-    p_bf = plan(cfg, INPUT_SHAPES["decode_32k"], TPU_V5E,
-                avg_prompt_len=32, allow_quant=False)
-    assert p_bf.quant_policy == "bf16"
-
-
 # ---------------------------------------------------------------------------
-# KV-cache quantization (PR-4): structure, engine param, planner
+# KV-cache quantization: structure, engine param
 # ---------------------------------------------------------------------------
 
 KV_FORMATS = ("bf16", "q8_0", "q4_0")
@@ -422,123 +347,15 @@ def test_engine_kv_quant_noop_for_recurrent():
     assert outs["bf16"] == outs["q4_0"]
 
 
-def test_plan_and_simulator_carry_kv_quant():
-    """dispatch.plan emits kv_quant beside megastep_k/admission/
-    quant_policy (quality-floor veto + recurrent no-op), and
-    simulate_kv_precision predicts the context-scaling win."""
-    from repro.core import TPU_V5E, a17_cpu, plan, simulate_kv_precision
-    from repro.configs.base import INPUT_SHAPES
-    cfg = get_config("deepseek-7b")
-    p = plan(cfg, INPUT_SHAPES["decode_32k"], TPU_V5E, avg_prompt_len=32)
-    assert p.kv_quant == "q4_0"           # compute-rich TPU: 4.5 bits win
-    assert "kv_quant=" in p.summary()
-    # applying the plan to a ModelConfig must carry the cache precision
-    # (config_overrides is the documented way to consume a plan)
-    assert p.config_overrides()["kv_quant"] == "q4_0"
-    p_floor = plan(cfg, INPUT_SHAPES["decode_32k"], TPU_V5E,
-                   avg_prompt_len=32, quality_floor_bits=8.0)
-    assert p_floor.kv_quant == "q8_0"
-    p_off = plan(cfg, INPUT_SHAPES["decode_32k"], TPU_V5E,
-                 avg_prompt_len=32, allow_quant=False)
-    assert p_off.kv_quant == "bf16"
-    p_train = plan(cfg, INPUT_SHAPES["train_4k"], TPU_V5E)
-    assert p_train.kv_quant == "bf16"     # no decode loop to feed
-    p_ssm = plan(get_config("mamba2-2.7b"), INPUT_SHAPES["decode_32k"],
-                 TPU_V5E, avg_prompt_len=32)
-    assert p_ssm.kv_quant == "bf16"       # recurrent: contract no-op
-
-    hw = a17_cpu(2)
-    sim = simulate_kv_precision(cfg, hw, kv_lens=(64, 32768), ks=(8,))
-    gain = lambda fmt, kvl: (sim[fmt][kvl][8].tokens_per_s
-                             / sim["bf16"][kvl][8].tokens_per_s)
-    # the cache-stream win exists at long context and grows with it
-    assert gain("q8_0", 32768) > 1.02
-    assert gain("q8_0", 32768) > gain("q8_0", 64)
-    assert gain("q4_0", 32768) > 1.0
-    # recurrent families: all formats predict identically (no-op)
-    simr = simulate_kv_precision(get_config("recurrentgemma-2b"), hw,
-                                 kv_lens=(4096,), ks=(8,))
-    assert simr["q4_0"][4096][8].tokens_per_s == \
-        simr["bf16"][4096][8].tokens_per_s
-
-
-def test_plan_kernel_backend_flips_quant_ordering():
-    """The planner's kernel_backend knob predicts the fused-dequant
-    flip this PR's kernels cause: priced against the XLA backend the
-    materialized q4_0 unpack (write + read of a bf16 view) drowns the
-    byte win and both weight and cache precision fall back to q8_0;
-    priced against the fused Pallas kernels (in-register dequant,
-    quantized-width HBM reads) q4_0 wins both. config_overrides emits
-    a consistent (kernels, use_pallas) pair either way."""
-    from repro.core import TPU_V5E, plan, simulate_kv_precision
-    from repro.core.precision import get_format
-    from repro.configs.base import INPUT_SHAPES
-    cfg = get_config("deepseek-7b")
-    shape = INPUT_SHAPES["decode_32k"]
-    p_pal = plan(cfg, shape, TPU_V5E, avg_prompt_len=32)  # default
-    p_xla = plan(cfg, shape, TPU_V5E, avg_prompt_len=32,
-                 kernel_backend="xla")
-    assert p_pal.kernel_backend == "pallas"
-    assert (p_pal.quant_policy, p_pal.kv_quant) == ("q4_0", "q4_0")
-    assert (p_xla.quant_policy, p_xla.kv_quant) == ("q8_0", "q8_0")
-    assert "kernels=" in p_pal.summary()
-    over_p, over_x = p_pal.config_overrides(), p_xla.config_overrides()
-    assert over_p["kernels"] == "pallas" and over_p["use_pallas"]
-    assert over_x["kernels"] == "xla" and not over_x["use_pallas"]
-    with pytest.raises(ValueError):
-        plan(cfg, shape, TPU_V5E, kernel_backend="mosaic")
-
-    # the flip's mechanism, pinned at the format level: only q4_0
-    # carries a materialized-unpack tax, so only its effective stream
-    # ratio degrades under XLA (q8_0's int8 widen fuses into the dot)
-    q4, q8 = get_format("q4_0"), get_format("q8_0")
-    assert q4.effective_stream_ratio("pallas") == q4.stream_ratio
-    assert q4.effective_stream_ratio("xla") > 1.0   # worse than bf16
-    assert q8.effective_stream_ratio("xla") == q8.stream_ratio
-
-    # and at the simulator level: per-backend q4-vs-q8 ordering at the
-    # plan's context on the same hardware
-    sim_p = simulate_kv_precision(cfg, TPU_V5E, kv_lens=(32768,),
-                                  ks=(8,))
-    sim_x = simulate_kv_precision(cfg, TPU_V5E, kv_lens=(32768,),
-                                  ks=(8,), kernel_backend="xla")
-    assert sim_p["q4_0"][32768][8].tokens_per_s > \
-        sim_p["q8_0"][32768][8].tokens_per_s
-    assert sim_x["q8_0"][32768][8].tokens_per_s > \
-        sim_x["q4_0"][32768][8].tokens_per_s
-
-
 # ---------------------------------------------------------------------------
-# Paged KV cache (PR-9): planner knob, donation pairing, byte audit
+# Paged KV cache: donation pairing, byte audit
 # ---------------------------------------------------------------------------
-
-
-def test_plan_never_pairs_pipelining_with_donation():
-    """Regression: plan() used to hardcode donate_carries=True even
-    when it chose pipeline_depth>1 — a donated carry can't be reused
-    while the previous megastep still holds it in flight, so the
-    engine had to warn and override at construction. The plan must
-    never emit the pair: donation is on exactly when the decode loop
-    is unpipelined."""
-    from repro.core import TPU_V5E, a17_cpu, plan
-    from repro.configs.base import INPUT_SHAPES
-    for arch in ("deepseek-7b", "mistral-nemo-12b", "mamba2-2.7b"):
-        cfg = get_config(arch)
-        for shape in INPUT_SHAPES.values():
-            for hw in (TPU_V5E, a17_cpu(2)):
-                for hit in (0.0, 0.6):
-                    p = plan(cfg, shape, hw, avg_prompt_len=512,
-                             prefix_hit_rate=hit)
-                    assert not (p.pipeline_depth > 1
-                                and p.donate_carries), \
-                        (arch, shape.name, hit, p.summary())
-                    assert p.donate_carries == (p.pipeline_depth < 2)
 
 
 def test_engine_overrides_donation_when_pipelined():
-    """The engine-side belt to the planner's suspenders: constructing
-    a pipelined engine with donated carries warns and overrides to
-    donate_carries=False instead of serving stale buffers."""
+    """Constructing a pipelined engine with donated carries warns and
+    overrides to donate_carries=False instead of serving stale
+    buffers."""
     import warnings
     cfg = reduced(get_config("deepseek-7b"), d_model=64, d_ff=128,
                   vocab_size=256, num_heads=2, num_kv_heads=1)
@@ -560,45 +377,6 @@ def test_engine_overrides_donation_when_pipelined():
     eng.submit(req)
     eng.run()
     assert req.output == m.reference_decode(params, req.prompt, 5)
-
-
-def test_plan_page_size_knob():
-    """page_size is emitted only when prefix reuse beats the gather
-    tax: 0 at the default (no-reuse) hit rate, a sweep size under
-    prefix-heavy traffic, and always 0 for recurrent families."""
-    from repro.core import TPU_V5E, plan, simulate_paging
-    from repro.configs.base import INPUT_SHAPES
-    cfg = get_config("deepseek-7b")
-    shape = INPUT_SHAPES["decode_32k"]
-    p0 = plan(cfg, shape, TPU_V5E, avg_prompt_len=512)
-    assert p0.page_size == 0
-    p_hit = plan(cfg, shape, TPU_V5E, avg_prompt_len=512,
-                 prefix_hit_rate=0.6)
-    assert p_hit.page_size > 0
-    assert "page_size=" in p_hit.summary()
-    p_ssm = plan(get_config("mamba2-2.7b"), shape, TPU_V5E,
-                 avg_prompt_len=512, prefix_hit_rate=0.6)
-    assert p_ssm.page_size == 0
-
-    # the analytic twin behind the knob: paged pool bytes sit far
-    # below the dense high-water prealloc at long context, and prefix
-    # hits buy back rider substeps (none without hits)
-    sim = simulate_paging(cfg, TPU_V5E, prompt_len=512, max_new=64,
-                          kv_len=4096, hit_rate=0.6)
-    assert 0 in sim and sim[0]["pool_bytes"] == sim[0]["dense_bytes"]
-    for p in (8, 16, 32):
-        assert sim[p]["pool_bytes"] < sim[0]["dense_bytes"]
-        assert sim[p]["rider_substeps_saved"] > 0
-    sim0 = simulate_paging(cfg, TPU_V5E, prompt_len=512, max_new=64,
-                           kv_len=4096, hit_rate=0.0)
-    assert all(sim0[p]["rider_substeps_saved"] == 0 for p in (8, 16, 32))
-    # recurrent families degenerate to dense (nothing to page)
-    simr = simulate_paging(get_config("mamba2-2.7b"), TPU_V5E,
-                           prompt_len=512, max_new=64, kv_len=4096,
-                           hit_rate=0.6)
-    for p in (8, 16, 32):
-        assert simr[p]["step"].tokens_per_s == simr[0]["step"].tokens_per_s
-        assert simr[p]["rider_substeps_saved"] == 0
 
 
 @pytest.mark.parametrize("page", (0, 8))

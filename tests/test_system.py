@@ -11,8 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import INPUT_SHAPES, get_config, reduced
-from repro.core import plan
+from repro.configs import get_config, reduced
 from repro.models import Model
 from repro.quant import quantize_tree
 from repro.serving import Request, SamplingConfig, ServingEngine
@@ -69,23 +68,6 @@ def test_train_quantize_serve_pipeline(tmp_path):
     engine_f.run()
     agree = np.mean([a == b for a, b in zip(rf.output, reqs[0].output)])
     assert agree >= 0.5, (rf.output, reqs[0].output)
-
-
-def test_dispatch_plan_configures_model():
-    """The hardware-aware planner's overrides produce a runnable model."""
-    cfg = get_config("deepseek-7b")
-    p = plan(cfg, INPUT_SHAPES["decode_32k"])
-    over = p.config_overrides()
-    assert over["fuse_qkv"] is True
-    # kernels wins over use_pallas in __post_init__, so pin both to
-    # keep this smoke test on the fast XLA path
-    small = dataclasses.replace(
-        reduced(cfg), **{**over, "use_pallas": False, "kernels": "xla"})
-    m = Model(small)
-    params = m.init(jax.random.PRNGKey(0))
-    logits, _ = m.forward(params, {"tokens": jnp.zeros((2, 8), jnp.int32)})
-    assert np.isfinite(np.asarray(logits, np.float32)).all()
-    assert p.summary()  # human-readable report exists
 
 
 def test_compile_cache_defers_to_env(monkeypatch):
