@@ -89,10 +89,11 @@ class ModelConfig:
     kv_quant: str = "bf16"         # cache precision: bf16|q8_0|q4_0
     use_pallas: bool = False       # use Pallas kernels (interpret on CPU)
     # Kernel backend: one switch for the whole fused-dequant path
-    # (quant_matmul decode GEMVs + the quantized-KV decode-attention
-    # kernel). "" (default) derives from use_pallas for backwards
-    # compatibility; an explicit "pallas"/"xla" wins and rewrites
-    # use_pallas to match, so call sites keep reading cfg.use_pallas.
+    # (quant_matmul for the layer GEMVs and a quantized lm_head, and the
+    # quantized-KV decode-attention kernel). "" (default) derives from
+    # use_pallas for backwards compatibility; an explicit "pallas"/"xla"
+    # wins and rewrites use_pallas to match, so call sites keep reading
+    # cfg.use_pallas.
     kernels: str = ""              # ""|"xla"|"pallas"
     remat: bool = True             # activation checkpointing per layer
     # Cost-calibration mode (launch/dryrun.py): python-loop the layer

@@ -69,10 +69,11 @@ def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, fmt: str,
     # Round the dequantized tile to the activation dtype and feed the
     # MXU an activation-dtype x activation-dtype dot with f32
     # accumulation — the op ops.matmul's XLA fallback runs on
-    # dequantize(w, out_dtype), on the same rounded operands. Only the
-    # f32 summation order over K differs (per block, and for q4_0 per
-    # half block), so a bf16 output can differ from the fallback's by
-    # one rounding step, rarely.
+    # dequantize(w, out_dtype), on the same rounded operands where the
+    # output is the activation dtype (an f32 lm_head's fallback keeps
+    # f32 weights). Only the f32 summation order over K differs (per
+    # block, and for q4_0 per half block), so a bf16 output can differ
+    # from the fallback's by one rounding step, rarely.
     if fmt == "q8_0":
         w = _dequant_rows(q_ref[...], s_ref[...], group)
         acc_ref[...] += jax.lax.dot(x, w.astype(x.dtype),

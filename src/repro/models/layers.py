@@ -76,12 +76,22 @@ def embed(params, tokens: jax.Array) -> jax.Array:
 
 
 def unembed(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """f32 logits of ``x`` over the (padded) vocabulary.
+
+    A quantized head follows ``cfg.kernels`` like every layer
+    projection: under "pallas" it streams through ``quant_matmul`` at its
+    stored width (each tile dequantized in VMEM, rounded to the
+    activation dtype, f32 accumulation and output), and a vocabulary
+    ``ops._quant_tiles`` cannot tile falls back to the XLA dequant. A
+    bf16 or tied head (the embedding is never quantized) is a plain dot.
+    """
     with jax.named_scope("lm_head"):
         if cfg.tie_embeddings:
             w = params["embedding"].T
         else:
             w = params["lm_head"]
-        logits = ops.matmul(x, w, out_dtype=jnp.float32)
+        logits = ops.matmul(x, w, use_pallas=cfg.use_pallas,
+                            out_dtype=jnp.float32)
         return constrain(logits, ("batch", "seq", "vocab"))
 
 

@@ -123,6 +123,34 @@ def test_quant_matmul_compiles(arch, fmt, one_chip):
                           ("bf16", str(K // GROUP), str(N))], shapes
 
 
+@pytest.mark.parametrize("arch,fmt,M", [("deepseek-7b", "q8_0", 8),
+                                        ("mistral-nemo-12b", "q4_0", 1)])
+def test_quant_matmul_lm_head_compiles(arch, fmt, M, one_chip):
+    """The benchmark cells' quantized heads (vocabulary-wide N, f32
+    logits) at their slot counts, with the tiles ``ops`` serves: one
+    call the benchmark's rule matches, as a layer GEMV."""
+    rule = json.loads((ROOT / "perfbench" / "kernels.json").read_text())
+    match = re.compile(rule["kernels"]["quant_matmul"]["match"])
+    cfg = get_config(arch)
+    K, N = cfg.d_model, cfg.padded_vocab
+    rows = K // 2 if fmt == "q4_0" else K
+    x = jax.ShapeDtypeStruct((M, K), jnp.bfloat16, sharding=one_chip)
+    w = QuantizedTensor(
+        jax.ShapeDtypeStruct((rows, N), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((K // GROUP, N), jnp.bfloat16,
+                             sharding=one_chip), fmt, GROUP)
+    bm, bn, bk = ops._quant_tiles(M, K, N, w)
+
+    def f(x, w):
+        return _qmm.quant_matmul(x, w, bm=bm, bn=bn, bk=bk,
+                                 out_dtype=jnp.float32)
+
+    text = _operand_text(jax.jit(f).lower(x, w).compile())
+    call = match.search(text)
+    assert call, (K, N)
+    assert re.search(rf"f32\[{M},{N}\]\S* custom-call\(", text)
+
+
 @pytest.mark.parametrize("K,N", [(64, 128), (128, 64), (640, 256)])
 def test_quant_matmul_q4_0_full_span_compiles(K, N, one_chip):
     """Narrow q4_0 widths (the CPU suite's test models) take one

@@ -507,6 +507,45 @@ def test_matmul_dispatch_misaligned_shapes(mkn, quant):
                                rtol=2e-4, atol=2e-4)
 
 
+# K steps of two tiles and N of four tiles at the tiles ops serves:
+# q8_0 bk 512, q4_0 bk 1024 (ops.DEFAULT_BK_Q4), bn 256
+@pytest.mark.parametrize("fmt,K,N", [("q8_0", 1024, 1024),
+                                     ("q4_0", 2048, 1024)])
+def test_unembed_quantized_head_through_kernel(fmt, K, N):
+    """A quantized ``lm_head`` under kernels="pallas" streams through
+    ``quant_matmul`` (interpret mode) and keeps f32 logits: they agree
+    with the f32 oracle up to the bf16 rounding of the dequantized
+    tiles, and pick the same token as the XLA path over the same
+    bf16-rounded weights."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import layers
+    quant = {"q8_0": quantize_q8_0, "q4_0": quantize_q4_0}[fmt]
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    x = jax.random.normal(k1, (3, 1, K), jnp.float32).astype(jnp.bfloat16)
+    w = quant(jax.random.normal(k2, (K, N), jnp.float32))
+    bk = ops.DEFAULT_BK_Q4 if fmt == "q4_0" else 512
+    assert ops._quant_tiles(3, K, N, w) == (3, 256, bk)
+    assert K // bk == 2
+    base = get_config("deepseek-7b")
+    assert not base.tie_embeddings
+    pallas = dataclasses.replace(base, kernels="pallas")
+    xla = dataclasses.replace(base, kernels="xla")
+    out = layers.unembed({"lm_head": w}, x, pallas)
+    assert out.dtype == jnp.float32 and out.shape == (3, 1, N)
+    want = ref.quant_matmul_ref(x.reshape(3, K), w, out_dtype=jnp.float32)
+    scale = float(np.abs(np.asarray(want)).max())
+    np.testing.assert_allclose(np.asarray(out).reshape(3, N),
+                               np.asarray(want), rtol=0, atol=0.01 * scale)
+    rounded = layers.unembed({"lm_head": dequantize(w, jnp.bfloat16)}, x,
+                             xla)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(rounded),
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_array_equal(np.argmax(np.asarray(out), -1),
+                                  np.argmax(np.asarray(rounded), -1))
+
+
 @pytest.mark.parametrize("val,expected", [
     ("1", True), ("true", True), ("TRUE", True), ("yes", True),
     ("0", False), ("false", False), ("False", False), ("FALSE", False),

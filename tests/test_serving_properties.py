@@ -35,7 +35,7 @@ from _hypothesis_compat import given, settings, st
 from repro.configs import get_config, reduced
 from repro.kernels import ops
 from repro.models import Model
-from repro.quant import QuantizedTensor, quantize_tree
+from repro.quant import QuantizedTensor, dequantize, quantize_tree
 from repro.serving import (Request, SamplingConfig, ServingEngine,
                            sample, sample_batched)
 
@@ -332,14 +332,24 @@ def test_pallas_engine_matches_reference(seed, quant, k):
     ``Model.reference_decode`` on the plain XLA model, for the same
     params and cache format, across both admission modes and megastep
     K ∈ {1, 8}. The cache format rides the weight format (quantized
-    weights + quantized cache is the fused kernel's target regime)."""
+    weights + quantized cache is the fused kernel's target regime).
+
+    The fused kernel rounds each dequantized tile to the activation
+    dtype. A layer GEMV's fallback does the same, but the f32-output
+    ``lm_head`` fallback multiplies the f32 dequantization; so the
+    reference reads the head at the width the kernel reads it, bf16,
+    and every other weight as stored."""
     rng = np.random.default_rng(seed)
     kv = "bf16" if quant == "bf16" else quant
     cfg, m, params = _model("deepseek-7b", quant, kv)
-    # every quantized layer GEMV takes the fused kernel, not the XLA
-    # fallback (which would compare XLA with XLA)
+    ref_params = params
+    if quant != "bf16":
+        ref_params = dict(params, lm_head=dequantize(params["lm_head"],
+                                                     jnp.bfloat16))
+    # every quantized layer GEMV and the head take the fused kernel, not
+    # the XLA fallback (which would compare XLA with XLA)
     for w in jax.tree_util.tree_leaves(
-            params["layers"],
+            (params["layers"], params["lm_head"]),
             is_leaf=lambda l: isinstance(l, QuantizedTensor)):
         if isinstance(w, QuantizedTensor):
             K, N = w.logical_shape[-2:]
@@ -355,7 +365,7 @@ def test_pallas_engine_matches_reference(seed, quant, k):
         for r in reqs:
             assert r.done
             ref = m.reference_decode(
-                params, r.prompt, r.max_new_tokens,
+                ref_params, r.prompt, r.max_new_tokens,
                 stepwise_prefill=(mode == "chunked"))
             assert r.output == ref, (mode, quant, k, r.uid,
                                      r.output, ref)

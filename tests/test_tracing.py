@@ -216,3 +216,38 @@ def test_megastep_hlo_names_scopes_and_kernels(kv, attention, all_greedy):
     # a scope or kernel name heads a location's name stack, or sits in it
     for name in ("lm_head", "sampler", "quant_matmul", attention):
         assert re.search(rf'loc\("([^"]*/)?{name}[/"]', text), name
+
+
+@pytest.mark.parametrize("kernels", ["pallas", "xla"])
+@pytest.mark.parametrize("fmt", ["q8_0", "q4_0"])
+def test_megastep_lm_head_route(fmt, kernels, monkeypatch):
+    """The route a quantized head takes, read from the megastep lowered
+    for a TPU (Mosaic, not interpret mode; nothing compiles or runs).
+    Under "pallas" the head is a ``quant_matmul`` call whose f32 output
+    spans the vocabulary, and no dequantized ``[d_model, vocab]`` array
+    exists; under "xla" it is the dequantized array, and no such call.
+    The vocabulary (512) is wider than every layer projection (at most
+    256), so a call of that width is the head's."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg = dataclasses.replace(_cfg(), vocab_size=512, quant_policy=fmt)
+    D, V = cfg.d_model, cfg.padded_vocab
+    model = Model(cfg)
+    params = quantize_tree(model.init(jax.random.PRNGKey(0)), fmt,
+                           cfg.quant_group)
+    eng = ServingEngine(model, params, slots=2, max_len=64, megastep_k=2,
+                        kernels=kernels, quant_policy=fmt)
+    text = eng._megastep.trace(eng.params, eng.cache, eng.state,
+                               eng._empty_admit(), False
+                               ).lower(lowering_platforms=("tpu",)).as_text()
+    head = re.compile(r'stablehlo\.custom_call @tpu_custom_call\(.*'
+                      r'kernel_name = "quant_matmul".*'
+                      rf'-> tensor<2x{V}xf32>')
+    dequantized = re.search(rf"tensor<{D}x{V}x(f32|bf16)>", text)
+    if kernels == "pallas":
+        assert head.search(text)
+        assert not dequantized, dequantized.group(0)
+    else:
+        assert not head.search(text)
+        assert "tpu_custom_call" not in text
+        assert dequantized
